@@ -18,7 +18,7 @@ def run(verbose: bool = False) -> List[Tuple[str, float, str]]:
     # validated kernel execution (small, CPU-interpret)
     x = jnp.ones((8 * 64, 128), jnp.float32)
     t0 = time.perf_counter()
-    out = ops.membw_op(x, n_chunks=8, n_iters=16)
+    out = ops.membw_op(x, n_chunks=8, n_iters=16, interpret=True)
     out.block_until_ready()
     us = (time.perf_counter() - t0) * 1e6
     rows.append(("membw_kernel_16iters", us, f"checksum={float(out.sum()):.1f}"))

@@ -72,6 +72,8 @@ def _worker() -> None:
 
 def run(verbose: bool = False) -> List[Tuple[str, float, str]]:
     env = dict(os.environ)
+    # the worker forces host devices; the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count="
                         f"{N_DEVICES}").strip()

@@ -96,6 +96,21 @@ MI300X = ChipSpec(
 
 CHIPS = {c.name: c for c in (TPU_V5E, MI250X_GCD, H100_SXM, MI300X)}
 
+#: ``jax.Device.device_kind`` -> spec, for chips this repo has run on. A
+#: TPU v5e reports "TPU v5 lite".
+CHIPS_BY_DEVICE_KIND: Mapping[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """The spec of an attached device; an unknown kind is an error, never a
+    default."""
+    try:
+        return CHIPS_BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"no ChipSpec for device_kind {kind!r}; known kinds: "
+            f"{sorted(CHIPS_BY_DEVICE_KIND)}") from None
+
 # ---------------------------------------------------------------------------
 # Paper Table III — measured relative response (% of the uncapped run) on
 # MI250X, averaged across arithmetic intensities (VAI) and data sizes (MB).

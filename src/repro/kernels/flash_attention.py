@@ -14,6 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tpu import compiler_params
 
 NEG_INF = -1e30
 
@@ -60,7 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool | None = None) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: [BH, Sq, D], k/v: [BH, Skv, D/Dv] -> [BH, Sq, Dv].
 
     Batch and heads are folded into the leading dim (GQA expansion happens in
@@ -73,10 +76,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bk = min(block_k, Skv)
     assert Sq % bq == 0 and Skv % bk == 0, (Sq, bq, Skv, bk)
     nq, nk = Sq // bq, Skv // bk
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    from jax.experimental.pallas import tpu as pltpu
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nk=nk)
     return pl.pallas_call(
@@ -94,5 +93,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
+        compiler_params=compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +14,7 @@ from repro.kernels import vai as vai_mod
 @functools.partial(jax.jit, static_argnames=("loopsize", "block_rows",
                                              "interpret"))
 def vai_op(a, b, c, *, loopsize: int, block_rows: int = 256,
-           interpret: Optional[bool] = None):
+           interpret: bool = False):
     return vai_mod.vai(a, b, c, loopsize=loopsize, block_rows=block_rows,
                        interpret=interpret)
 
@@ -23,7 +22,7 @@ def vai_op(a, b, c, *, loopsize: int, block_rows: int = 256,
 @functools.partial(jax.jit, static_argnames=("n_chunks", "n_iters",
                                              "interpret"))
 def membw_op(x, *, n_chunks: int, n_iters: int,
-             interpret: Optional[bool] = None):
+             interpret: bool = False):
     return mb.membw(x, n_chunks=n_chunks, n_iters=n_iters,
                     interpret=interpret)
 
@@ -32,7 +31,7 @@ def membw_op(x, *, n_chunks: int, n_iters: int,
                                              "interpret"))
 def flash_attention_op(q, k, v, *, causal: bool = True, block_q: int = 256,
                        block_k: int = 256,
-                       interpret: Optional[bool] = None):
+                       interpret: bool = False):
     """q: [B, Sq, Hq, D]; k/v: [B, Skv, Hkv, D/Dv]. GQA-expands KV, folds
     (batch, heads) into the kernel's leading grid dim."""
     B, Sq, Hq, D = q.shape

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+from repro.kernels.tpu import LANE, compiler_params
+
 DEFAULT_BLOCK_ROWS = 256
 
 
@@ -41,7 +42,7 @@ def _vai_kernel(a_ref, b_ref, c_ref, o_ref, *, loopsize: int):
 
 def vai(a: jax.Array, b: jax.Array, c: jax.Array, *, loopsize: int,
         block_rows: int = DEFAULT_BLOCK_ROWS,
-        interpret: bool | None = None) -> jax.Array:
+        interpret: bool = False) -> jax.Array:
     """a, b, c: [rows, 128] f32; returns updated c.
 
     ``loopsize`` must be a non-negative int (0 = the stream-copy c <- b);
@@ -69,8 +70,6 @@ def vai(a: jax.Array, b: jax.Array, c: jax.Array, *, loopsize: int,
         raise ValueError(
             f"block_rows={block_rows} does not tile the {rows}-row input: "
             f"rows % {br} == {rows % br} (pick a divisor of {rows})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     grid = (rows // br,)
     spec = pl.BlockSpec((br, LANE), lambda i: (i, 0))
     return pl.pallas_call(
@@ -79,6 +78,7 @@ def vai(a: jax.Array, b: jax.Array, c: jax.Array, *, loopsize: int,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(a, b, c)
 

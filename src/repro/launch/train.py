@@ -27,6 +27,7 @@ from repro.power.policies import PolicyLike
 from repro.checkpoint import Checkpointer, restore
 from repro.data import SyntheticPipeline
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as model_mod
 from repro.models.transformer import Runtime
 from repro.optim import OptConfig, init_opt_state
@@ -183,6 +184,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     shape = SHAPES_BY_NAME[args.shape]
     if args.reduced:

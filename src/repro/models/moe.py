@@ -33,13 +33,6 @@ from repro.models.common import ParamMaker, gated_mlp, gated_mlp_params, shard
 
 CAPACITY_FACTOR = 1.25
 
-try:                                # jax >= 0.6: public API, check_vma kw
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-except AttributeError:              # jax 0.4.x: experimental, check_rep kw
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 
 def moe_params(mk: ParamMaker, prefix: str, cfg: ModelConfig,
                tp: int = 1) -> Dict:
@@ -195,9 +188,9 @@ def moe_block_ep(p: Dict, cfg: ModelConfig, x: jax.Array, *,
                                model_axis=model_axis, all_axes=all_axes,
                                dispatch_dtype=dispatch_dtype,
                                capacity_factor=capacity_factor)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=(xs, wspec), out_specs=(xs, P()),
-        **_SHARD_MAP_NOCHECK)(x, pp)
+        check_vma=False)(x, pp)
     if cfg.n_shared_experts:
         y = y + gated_mlp(p["shared"], x, cfg.act)
     return y, aux

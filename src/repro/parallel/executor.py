@@ -12,7 +12,9 @@ hoped for (see docs/BACKENDS.md for the full story):
   reciprocal multiply or reassociate a constant product;
 * the executable is compiled with ``xla_cpu_max_isa=AVX`` so LLVM cannot
   contract ``a*b+c`` into an FMA (AVX has no FMA instruction — 256-bit
-  vectors are kept, only fused multiply-adds are off the table);
+  vectors are kept, only fused multiply-adds are off the table). jax
+  0.9.0 ignores this per-compile option; there the process needs
+  ``XLA_FLAGS=--xla_cpu_max_isa=AVX`` for parity;
 * the one reused division in every ``(c/f)/t`` utilization chain sits
   behind ``lax.optimization_barrier`` so XLA's algebraic simplifier
   cannot combine the two divides into ``c/(f*t)``;
@@ -395,8 +397,8 @@ class ShardedExecutor:
             mm[:m.size] = m
             p, m = pp, mm
         comp = self._segment_kernel(cap)
-        from jax.experimental import enable_x64
-        with enable_x64():
+        import jax
+        with jax.enable_x64(True):
             out = np.asarray(comp(p, m))
         self.stats["kernel_calls"] += 1
         return out[:, :nseg]
@@ -411,16 +413,11 @@ class ShardedExecutor:
 
     def _compile(self, kern, in_specs, args):
         import jax
-        from jax.experimental import enable_x64
         from repro.parallel.sharding import named_sharding_tree
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:      # jax < 0.6 spelling
-            from jax.experimental.shard_map import shard_map
-        sm = shard_map(kern, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=self._out_specs(kern))
+        sm = jax.shard_map(kern, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=self._out_specs(kern))
         opts = {"xla_cpu_max_isa": self.isa} if self.isa else None
-        with enable_x64():
+        with jax.enable_x64(True):
             comp = jax.jit(
                 sm, in_shardings=named_sharding_tree(in_specs, self.mesh)
             ).lower(*args).compile(compiler_options=opts)
@@ -435,7 +432,7 @@ class ShardedExecutor:
 
     def _run_decide(self, kind, nf, p, m, dur, fr, pfr, sc, fgrid, pgrid,
                     dur_scalar: bool, f_scalar: bool = True):
-        from jax.experimental import enable_x64
+        import jax
         from jax.sharding import PartitionSpec as P
         n = p.size
         cap = self._capacity(n)
@@ -461,7 +458,7 @@ class ShardedExecutor:
             comp = self._compile(kern, specs,
                                  (p, m, dur, fr, pfr, sc, fgrid, pgrid))
             self._compiled[key] = comp
-        with enable_x64():
+        with jax.enable_x64(True):
             out = comp(p, m, dur, fr, pfr, sc, fgrid, pgrid)
             out = [np.asarray(x) for x in out]
         self.stats["kernel_calls"] += 1

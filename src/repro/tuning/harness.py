@@ -11,8 +11,9 @@ Two interchangeable backends produce the same :class:`Measurement` record
   hardware, no clocks, no RNG — so CI can pin exact outputs, and
   bit-for-bit with the scalar :meth:`measure_one` path per the surface
   parity contract.
-* :class:`WallClockBackend` — times the real jitted kernel (best of
-  ``repeats``, after a warmup compile+run) and anchors the analytic
+* :class:`WallClockBackend` — times the kernel compiled for the TPU (best
+  of ``repeats``, after a warmup compile+run; never the Pallas
+  interpreter, so it needs a TPU) and anchors the analytic
   profile to the observed wall clock: the roofline terms are rescaled so
   ``step_time(profile, 1.0)`` equals the measured time, then the
   frequency/power response comes from the same transfer surface. On a
@@ -184,12 +185,13 @@ class WallClockBackend(SimulatedBackend):
     def _time_candidate(self, space: KernelSpace,
                         candidate: Candidate) -> float:
         import jax
-        out = space._run(candidate)                  # warmup: compile + run
-        jax.block_until_ready(out)
+        fn, args = space.program(candidate, interpret=False)
+        args = jax.device_put(args)                 # time the kernel alone
+        jax.block_until_ready(fn(*args))            # warmup: compile + run
         best = float("inf")
         for _ in range(self.repeats):
             t0 = self.timer()
-            jax.block_until_ready(space._run(candidate))
+            jax.block_until_ready(fn(*args))
             best = min(best, self.timer() - t0)
         return best
 

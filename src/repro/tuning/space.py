@@ -5,9 +5,13 @@ kernel gets a :class:`KernelSpace` that (1) enumerates its tiling knobs,
 (2) prunes candidates that can't run well on the target chip *before*
 anything is timed — TPU tiling alignment (f32 sublane tile is ``(8, 128)``,
 the MXU systolic array is ``128 x 128``), grid divisibility, and the
-per-grid-step VMEM footprint — and (3) validates every surviving candidate
-against the pure-jnp oracles in :mod:`repro.kernels.ref` in interpret mode
-before it is allowed into the measurement harness.
+per-grid-step VMEM footprint (pipelined blocks counted twice, as Pallas
+double-buffers them, plus the kernel body's block-sized values) against
+the scoped VMEM limit the kernels compile with,
+:data:`repro.kernels.tpu.VMEM_LIMIT_BYTES` — and (3) validates every
+surviving candidate against the pure-jnp oracles in
+:mod:`repro.kernels.ref` in interpret mode before it is allowed into the
+measurement harness.
 
 Validation contract: the VAI and membw spaces draw small *integer-valued*
 float32 inputs, so every product and partial sum is exactly representable
@@ -29,19 +33,18 @@ rendering collapse to the bare roofline (bit-for-bit
 """
 from __future__ import annotations
 
+import functools
 import operator
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.hardware import ChipSpec, TPU_V5E
 from repro.core.power_model import ChipModel, StepProfile
+from repro.kernels.membw import tile_rows
+from repro.kernels.tpu import LANE, SUBLANE, VMEM_LIMIT_BYTES
 
-#: lane width of every TPU tile (last-dim constraint)
-LANE = 128
-#: f32 minimum sublane tile (second-to-last dim must be a multiple)
-SUBLANE_F32 = 8
 #: MXU systolic-array edge — matmul block shapes should be multiples
 MXU = 128
 
@@ -122,8 +125,8 @@ class KernelSpace:
     Subclasses define ``kernel``, ``_raw_configs()`` (the unpruned knob
     lattice, in enumeration order), ``_prune(config) -> Optional[str]``
     (a rejection reason, or None to keep), ``_candidate(config)`` (attach
-    the analytic cost), ``_run(candidate)`` / ``_reference(candidate)``
-    (interpret-mode execution vs the jnp oracle) and
+    the analytic cost), ``program(candidate, interpret)`` (the jitted
+    kernel and its inputs), ``_reference(candidate)`` (the jnp oracle) and
     ``profile(candidate, model, perf)`` (the roofline rendering).
     """
 
@@ -137,7 +140,7 @@ class KernelSpace:
                  vmem_limit_bytes: Optional[int] = None):
         self.chip = ChipModel(chip).spec
         self.vmem_limit_bytes = int(
-            self.chip.vmem_bytes if vmem_limit_bytes is None
+            VMEM_LIMIT_BYTES if vmem_limit_bytes is None
             else vmem_limit_bytes)
         self._kept: Optional[List[Candidate]] = None
         self._pruned: Optional[List[Tuple[Config, str]]] = None
@@ -166,7 +169,8 @@ class KernelSpace:
 
         Returns the max abs error (0.0 for the exact spaces); raises
         :class:`ValidationError` on divergence."""
-        out = np.asarray(self._run(candidate))
+        fn, args = self.program(candidate, interpret=True)
+        out = np.asarray(fn(*args))
         want = np.asarray(self._reference(candidate))
         err = float(np.max(np.abs(out.astype(np.float64)
                                   - want.astype(np.float64)))) \
@@ -195,7 +199,12 @@ class KernelSpace:
     def _candidate(self, config: Config) -> Candidate:
         raise NotImplementedError
 
-    def _run(self, candidate: Candidate):
+    def program(self, candidate: Candidate, interpret: bool
+                ) -> Tuple[Callable, Tuple]:
+        """``(fn, args)``: the candidate's kernel as a jitted function and
+        the space's inputs. ``interpret=True`` runs the Pallas interpreter
+        (validation, on any backend); ``False`` compiles the kernel for the
+        TPU, as :class:`~repro.tuning.harness.WallClockBackend` times it."""
         raise NotImplementedError
 
     def _reference(self, candidate: Candidate):
@@ -252,16 +261,20 @@ class VaiSpace(KernelSpace):
         br, L = cfg["block_rows"], cfg["loopsize"]
         if L < 0:
             return "negative-loopsize"
-        if br <= 0 or br % SUBLANE_F32:
-            return f"sublane-misaligned (block_rows % {SUBLANE_F32} != 0)"
+        if br <= 0 or br % SUBLANE:
+            return f"sublane-misaligned (block_rows % {SUBLANE} != 0)"
         if self.rows % min(br, self.rows):
             return f"indivisible ({self.rows} rows % {br})"
-        # a, b, c blocks in + the written block out, all resident
-        footprint = 4 * min(br, self.rows) * LANE * 4
+        footprint = self._footprint(br)
         if footprint > self.vmem_limit_bytes:
             return (f"vmem-overflow ({footprint} B > "
                     f"{self.vmem_limit_bytes} B)")
         return None
+
+    def _footprint(self, block_rows: int) -> int:
+        # a, b, c blocks in + the written block out, each double-buffered,
+        # and the body's x, y, z and accumulator values
+        return (2 * 4 + 4) * min(block_rows, self.rows) * LANE * 4
 
     def _candidate(self, config: Config) -> Candidate:
         from repro.kernels.vai import vai_flops_bytes
@@ -270,7 +283,7 @@ class VaiSpace(KernelSpace):
         flops, byts = vai_flops_bytes(self.n_elems, cfg["loopsize"])
         return Candidate(kernel=self.kernel, config=config,
                          flops=float(flops), hbm_bytes=float(byts),
-                         vmem_bytes=4 * br * LANE * 4,
+                         vmem_bytes=self._footprint(br),
                          grid_steps=self.rows // br)
 
     # integer-valued f32 inputs: every x*y + acc is exact, so the kernel
@@ -284,11 +297,13 @@ class VaiSpace(KernelSpace):
                 for _ in range(3))
         return self._inputs
 
-    def _run(self, candidate: Candidate):
+    def program(self, candidate: Candidate, interpret: bool):
         from repro.kernels import ops
-        a, b, c = self._get_inputs()
-        return ops.vai_op(a, b, c, loopsize=candidate.get("loopsize"),
-                          block_rows=candidate.get("block_rows"))
+        return (functools.partial(ops.vai_op,
+                                  loopsize=candidate.get("loopsize"),
+                                  block_rows=candidate.get("block_rows"),
+                                  interpret=interpret),
+                self._get_inputs())
 
     def _reference(self, candidate: Candidate):
         from repro.kernels import ref
@@ -342,13 +357,19 @@ class MembwSpace(KernelSpace):
         if self.total_rows % n:
             return f"indivisible ({self.total_rows} rows % {n} chunks)"
         chunk_rows = self.total_rows // n
-        if chunk_rows % SUBLANE_F32:
-            return f"sublane-misaligned (chunk_rows % {SUBLANE_F32} != 0)"
-        footprint = 2 * chunk_rows * LANE * 4          # chunk in + row out
+        if chunk_rows % SUBLANE:
+            return f"sublane-misaligned (chunk_rows % {SUBLANE} != 0)"
+        footprint = self._footprint(chunk_rows)
         if footprint > self.vmem_limit_bytes:
             return (f"vmem-overflow ({footprint} B > "
                     f"{self.vmem_limit_bytes} B)")
         return None
+
+    @staticmethod
+    def _footprint(chunk_rows: int) -> int:
+        # the kernel reads a chunk tile by tile: one tile in and the
+        # (8, 128) accumulator out, each double-buffered, and the loaded tile
+        return (3 * tile_rows(chunk_rows) + 2 * SUBLANE) * LANE * 4
 
     def _candidate(self, config: Config) -> Candidate:
         n = dict(config)["n_chunks"]
@@ -356,15 +377,17 @@ class MembwSpace(KernelSpace):
         chunk_bytes = chunk_rows * LANE * 4
         working_set = n * chunk_bytes
         # cold pass reads the working set once; re-reads hit VMEM/cache
-        # only if the whole rotation fits under the boundary
-        if working_set <= self.vmem_limit_bytes:
+        # only if the whole rotation fits under the chip's physical VMEM.
+        # (On a TPU a block is re-fetched whenever its index changes, so
+        # this cache model is an open question for the chip benchmark.)
+        if working_set <= self.chip.vmem_bytes:
             traffic = float(working_set)
         else:
             traffic = float(chunk_bytes) * self.n_iters
         return Candidate(kernel=self.kernel, config=config,
                          flops=float(chunk_rows * LANE * self.n_iters),
                          hbm_bytes=traffic,
-                         vmem_bytes=2 * chunk_bytes,
+                         vmem_bytes=self._footprint(chunk_rows),
                          grid_steps=self.n_iters)
 
     def _get_x(self):
@@ -374,11 +397,12 @@ class MembwSpace(KernelSpace):
                                    ).astype(np.float32)
         return self._x
 
-    def _run(self, candidate: Candidate):
+    def program(self, candidate: Candidate, interpret: bool):
         from repro.kernels import ops
-        return ops.membw_op(self._get_x(),
-                            n_chunks=candidate.get("n_chunks"),
-                            n_iters=self.n_iters)
+        return (functools.partial(ops.membw_op,
+                                  n_chunks=candidate.get("n_chunks"),
+                                  n_iters=self.n_iters, interpret=interpret),
+                (self._get_x(),))
 
     def _reference(self, candidate: Candidate):
         from repro.kernels import ref
@@ -445,7 +469,8 @@ class FlashAttentionSpace(KernelSpace):
         d, dv = self.head_dim, self.value_dim
         blocks = bq * d + bk * d + bk * dv + bq * dv    # q, k, v, o
         scratch = bq + bq + bq * dv                     # m, l, acc
-        return 4 * (blocks + scratch)
+        body = 2 * bq * bk + 2 * bq * dv                # s, p; p @ v, acc
+        return 4 * (2 * blocks + scratch + body)
 
     def _prune(self, config: Config) -> Optional[str]:
         cfg = dict(config)
@@ -495,12 +520,15 @@ class FlashAttentionSpace(KernelSpace):
                                    self.value_dim), jnp.float32))
         return self._qkv
 
-    def _run(self, candidate: Candidate):
+    def program(self, candidate: Candidate, interpret: bool):
+        import jax
         from repro.kernels.flash_attention import flash_attention
-        q, k, v = self._get_qkv()
-        return flash_attention(q, k, v, causal=self.causal,
-                               block_q=candidate.get("block_q"),
-                               block_k=candidate.get("block_k"))
+        return (jax.jit(functools.partial(
+                    flash_attention, causal=self.causal,
+                    block_q=candidate.get("block_q"),
+                    block_k=candidate.get("block_k"),
+                    interpret=interpret)),
+                self._get_qkv())
 
     def _reference(self, candidate: Candidate):
         from repro.kernels import ref

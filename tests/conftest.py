@@ -49,8 +49,11 @@ def reduced_f32(arch: str):
 
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 420) -> str:
-    """Run a snippet in a fresh process with N host devices."""
+    """Run a snippet in a fresh process with N host devices. The child is
+    pinned to the CPU: on a machine with a chip, the parent (which has
+    imported JAX) already holds it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,

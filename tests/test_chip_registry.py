@@ -83,3 +83,12 @@ def test_response_tables_monotone_and_projectable(name, kind):
     rows = project(list(caps), kind, tables=rt)
     assert len(rows) == len(caps)
     assert all(np.isfinite(r.savings_pct) for r in rows)
+
+
+def test_device_kind_table_maps_v5e_and_rejects_unknown():
+    from repro.core.hardware import TPU_V5E, chip_for_device_kind
+    assert chip_for_device_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="no ChipSpec"):
+        chip_for_device_kind("TPU v6 lite")
+    with pytest.raises(ValueError, match="no ChipSpec"):
+        chip_for_device_kind("cpu")

@@ -63,12 +63,8 @@ def test_collective_bytes_counted():
     def g(a):
         return jax.lax.psum(a, "x")
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:          # moved to jax.* after 0.4.x
-        from jax.experimental.shard_map import shard_map
-    sm = shard_map(g, mesh=mesh, in_specs=P(None, None),
-                   out_specs=P(None, None))
+    sm = jax.shard_map(g, mesh=mesh, in_specs=P(None, None),
+                       out_specs=P(None, None))
     c = jax.jit(sm).lower(
         jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()
     t = analyze_hlo(c.as_text())

@@ -287,3 +287,38 @@ def test_from_serving_study_roundtrip(served):
     before = w._store.total_energy_j()
     serve(eng, reqs)
     assert w._store.total_energy_j() == before
+
+
+@pytest.mark.slow
+def test_launch_serve_run_answers_every_request():
+    """The serving entry point as chip_smoke.py calls it, at a tiny size:
+    seeded log-uniform prompts under Poisson arrivals, every request
+    answered with its full budget."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.launch import serve as serve_launch
+    cfg = dataclasses.replace(get_config("stablelm-12b").reduced(),
+                              n_layers=2)
+    engine, reqs, rep = serve_launch.run(
+        cfg, requests=5, slots=2, max_len=64, prompt_lens=(4, 40),
+        new_tokens=4, seed=3)
+    lens = [len(r.prompt) for r in reqs]
+    assert all(4 <= n <= 40 for n in lens) and len(set(lens)) > 1
+    assert [len(o) for o in rep.outputs] == [4] * 5
+    assert engine.n_prefills == 5
+    again = serve_launch.make_requests(cfg, 5, (4, 40), 4, seed=3)
+    assert [len(r.prompt) for r in again] == lens
+
+
+@pytest.mark.parametrize("arch", ["recurrentgemma-2b", "mamba2-2.7b",
+                                  "seamless-m4t-large-v2",
+                                  "llama-3.2-vision-11b"])
+def test_launch_serve_refuses_non_slot_families(arch, monkeypatch, capsys):
+    """The launcher drives the slot engine only and says so, before it
+    builds anything, for the families it does not serve."""
+    from repro.launch import serve as serve_launch
+    monkeypatch.setattr("sys.argv", ["serve", "--arch", arch, "--reduced"])
+    with pytest.raises(SystemExit) as e:
+        serve_launch.main()
+    assert e.value.code == 2
+    assert "slot families" in capsys.readouterr().err

@@ -141,3 +141,25 @@ def test_opt_state_structure():
     assert int(new_opt["step"]) == 1
     assert m["grad_norm"] > 0
     assert float(jnp.max(jnp.abs(new_p["a"] - params["a"]))) > 0
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without it
+    the cache goes to the fixed .jax_cache/ at the checkout root."""
+    import jax
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        assert path == str(compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        root = compile_cache.DEFAULT_DIR.parent
+        assert compile_cache.DEFAULT_DIR.name == ".jax_cache"
+        assert (root / "chip_smoke.py").exists()
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -143,6 +143,27 @@ class TestHarness:
                 assert meas.time_s[i, j] == t
                 assert meas.power_w[i, j] == p
 
+    def test_wallclock_times_the_compiled_kernel(self):
+        """WallClockBackend asks the space for the compiled program
+        (``interpret=False``); only validation asks for the interpreter.
+        The CPU cannot compile a Mosaic kernel, so the spy hands back the
+        interpreted program to let the timing loop run here."""
+        from repro.tuning import WallClockBackend
+        space = VaiSpace(n_elems=1 << 14, loopsizes=(8,),
+                         block_rows_options=(128,))
+        real, asked = space.program, []
+
+        def spy(cand, interpret):
+            asked.append(interpret)
+            return real(cand, interpret=True)
+
+        space.program = spy
+        meas = WallClockBackend(TPU_V5E, repeats=2).measure(
+            space, freq_fracs=[1.0], validate=True)
+        assert asked == [True, False]
+        assert meas.source == "wallclock:tpu-v5e"
+        assert np.all(meas.time_s > 0)
+
     def test_ideal_perf_reproduces_vai_profile(self):
         """PerfParams.ideal() collapses the space's profile to
         ChipModel.vai_profile bit-for-bit — the run_sweep re-seat
