@@ -92,7 +92,7 @@ def run(verbose: bool = False) -> List[Tuple[str, float, str]]:
 
     sess = EnergySession(policy="energy-aware", slowdown_budget=0.0)
     eng.session = sess
-    eng.n_prefills = eng.n_steps = 0
+    eng.n_prefills = 0
     rep = serve(eng, reqs, arrivals=arrivals)
     t_cont = rep.wall_s
 

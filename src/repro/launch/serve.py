@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -64,6 +64,40 @@ def run(cfg: ModelConfig, *, requests: int = 16, slots: int = 8,
     report = serve(engine, reqs, poisson_arrivals(requests, rate, seed),
                    temperature=temperature)
     return engine, reqs, report
+
+
+def latencies_ms(rep: ServeReport) -> Dict[str, np.ndarray]:
+    """From the report's records, in ms: each request's wait in the queue
+    (due to admitted) and time to first token, and every gap between two
+    consecutive tokens of one request."""
+    t, r = rep.ticks, rep.requests
+    stepped = r.first_tick >= 0
+    gaps = [np.diff(t.read_s[a:b + 1]) for a, b in
+            zip(r.first_tick[stepped], r.last_tick[stepped])]
+    return {"queue wait": 1e3 * (r.admit_s - r.due_s),
+            "TTFT": 1e3 * (t.read_s[r.first_tick[stepped]]
+                           - r.due_s[stepped]),
+            "ITL": 1e3 * np.concatenate([np.zeros(0)] + gaps)}
+
+
+def slowest_tick(rep: ServeReport) -> Optional[str]:
+    """The longest decode tick (from the previous step's tokens on the host
+    to its own) and the phases that make it up."""
+    t = rep.ticks
+    if rep.n_steps < 2:
+        return None
+    k = int(np.argmax(np.diff(t.read_s))) + 1
+    ms = {"tick": t.read_s[k] - t.read_s[k - 1],
+          # the previous tick's bookkeeping, and any idle turns after it
+          "book": t.start_s[k] - t.read_s[k - 1],
+          "admit": t.admit_s[k], "step": t.step_s[k], "wait": t.wait_s[k]}
+    ms = {name: 1e3 * v for name, v in ms.items()}
+    other = ms["tick"] - sum(v for name, v in ms.items() if name != "tick")
+    return (f"slowest tick {k}: {ms['tick']:.1f} ms = book {ms['book']:.1f}"
+            f" + admit {ms['admit']:.1f} ({t.admitted[k]} requests, "
+            f"{t.prompt_tokens[k]} prompt tokens) + step {ms['step']:.1f}"
+            f" + wait {ms['wait']:.1f} ({t.active[k]} slots attending "
+            f"{t.kv_positions[k]} positions) + other {other:.1f}")
 
 
 def main() -> None:
@@ -116,10 +150,17 @@ def main() -> None:
         rate=args.rate, temperature=args.temperature,
         session=session)
     for i, o in enumerate(rep.outputs[:4]):
-        print(f"req{i} (prompt {len(reqs[i].prompt)}): {o.tolist()}")
+        print(f"req{i} (prompt {rep.requests.prompt_len[i]}): {o.tolist()}")
     print(f"{len(reqs)} requests  {rep.tokens_out} tokens  "
           f"{rep.n_steps} decode steps  occupancy {rep.occupancy_mean:.2f}"
           f"  wall {rep.wall_s:.2f} s")
+    for name, ms in latencies_ms(rep).items():
+        if ms.size:
+            p50, p95 = np.percentile(ms, [50, 95])
+            print(f"{name:<10} p50 {p50:9.1f} ms  p95 {p95:9.1f} ms")
+    line = slowest_tick(rep)
+    if line:
+        print(line)
     s = session.summary()
     print(f"policy {s['policy']}  energy {s['energy_j']:.1f} J  "
           f"savings {s['savings_pct']:.1f}%  "

@@ -24,6 +24,7 @@ causal-cache families (closing the pad-as-context bug there too).
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -99,6 +100,21 @@ def _sample_tokens(logits: jax.Array, temperature: jax.Array,
                         lambda _: greedy, None)
 
 
+def decode_step(cfg: ModelConfig, rt: Runtime, params, state, pos, tokens,
+                temps, active, key):
+    """One token for every slot of the pool (the engine's ``decode_step``
+    program): per-slot positions, per-slot sampling temperatures."""
+    key, sub = jax.random.split(key)
+    logits, state = decode_mod.decode_step(
+        cfg, rt, params, tokens[:, None], pos, state)
+    nxt = _sample_tokens(logits[:, 0, :cfg.vocab_size], temps, sub)
+    # inactive slots hold position/token so an inserted prefix starts
+    # clean; their cache writes land on dead rows (never attended)
+    pos = pos + active.astype(jnp.int32)
+    tokens = jnp.where(active, nxt, tokens)
+    return state, pos, tokens, nxt, key
+
+
 @dataclass
 class Request:
     prompt: np.ndarray            # [S] int32
@@ -129,7 +145,10 @@ class ContinuousEngine:
 
     With a ``session``, each scheduler tick reports its prefill count and
     decode step as distinct roofline profiles via ``observe_many`` — the
-    per-phase power-policy hook."""
+    per-phase power-policy hook.
+
+    Its programs read ``jit_prefill``, ``jit_insert`` and
+    ``jit_decode_step`` in a device trace, whatever the page."""
 
     def __init__(self, cfg: ModelConfig, rt: Runtime, params,
                  max_slots: int = 8, max_len: int = 256, page: int = 16,
@@ -167,9 +186,11 @@ class ContinuousEngine:
         # backend it serializes the per-step cache copies (the runtime can't
         # double-buffer a donated input), costing ~30% per step
         donate = (1, 2, 3, 6) if jax.default_backend() != "cpu" else ()
-        self._step_fn = jax.jit(self._step_impl, donate_argnums=donate)
+        # named for the device trace: jit_decode_step
+        step = functools.update_wrapper(
+            functools.partial(decode_step, cfg, rt), decode_step)
+        self._step_fn = jax.jit(step, donate_argnums=donate)
         self.n_prefills = 0
-        self.n_steps = 0
 
     # ------------------------------------------------------------- prefill
     def _bucket(self, length: int) -> int:
@@ -184,14 +205,14 @@ class ContinuousEngine:
     def _make_prefill(self, page: int):
         cfg, rt = self.cfg, self.rt
 
-        def f(params, tokens, length, temperature, key):
+        def prefill(params, tokens, length, temperature, key):
             logits, state = decode_mod.prefill(
                 cfg, rt, params, {"tokens": tokens}, page, lengths=length)
             tok = _sample_tokens(logits[:, 0, :cfg.vocab_size],
                                  temperature, key)
             return tok[0], state
 
-        return jax.jit(f)
+        return jax.jit(prefill)
 
     def prefill(self, request: Request, temperature: float = 0.0) -> Prefix:
         """Run one prompt through the trunk; returns the :class:`Prefix`
@@ -219,8 +240,8 @@ class ContinuousEngine:
 
     # -------------------------------------------------------------- insert
     def _make_insert(self, page: int):
-        def f(state, pos, tokens, temps, prefix_state, token, slot, length,
-              temperature):
+        def insert(state, pos, tokens, temps, prefix_state, token, slot,
+                   length, temperature):
             def put(c, u):
                 # c: [..., slots, max_len, ...]; u: [..., 1, page, ...] —
                 # the slot axis follows the (scanned) layer axis everywhere
@@ -233,7 +254,7 @@ class ContinuousEngine:
                     tokens.at[slot].set(token),
                     temps.at[slot].set(temperature))
 
-        return jax.jit(f, donate_argnums=(0, 1, 2, 3))
+        return jax.jit(insert, donate_argnums=(0, 1, 2, 3))
 
     def insert(self, prefix: Prefix, slot: int) -> None:
         """Scatter the prefix rows into ``slot`` and arm its position, last
@@ -248,17 +269,6 @@ class ContinuousEngine:
             jnp.int32(prefix.length), jnp.float32(prefix.temperature))
 
     # ------------------------------------------------------ generate_step
-    def _step_impl(self, params, state, pos, tokens, temps, active, key):
-        key, sub = jax.random.split(key)
-        logits, state = decode_mod.decode_step(
-            self.cfg, self.rt, params, tokens[:, None], pos, state)
-        nxt = _sample_tokens(logits[:, 0, :self.cfg.vocab_size], temps, sub)
-        # inactive slots hold position/token so an inserted prefix starts
-        # clean; their cache writes land on dead rows (never attended)
-        pos = pos + active.astype(jnp.int32)
-        tokens = jnp.where(active, nxt, tokens)
-        return state, pos, tokens, nxt, key
-
     def generate_step(self, active=None) -> jax.Array:
         """Advance every (active) slot one token; returns the [max_slots]
         int32 tokens sampled this step (inactive entries are meaningless)."""
@@ -267,7 +277,6 @@ class ContinuousEngine:
         self._state, self._pos, self._tokens, toks, self._key = \
             self._step_fn(self.params, self._state, self._pos, self._tokens,
                           self._temps, act, self._key)
-        self.n_steps += 1
         return toks
 
     # ------------------------------------------------------------- energy

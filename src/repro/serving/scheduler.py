@@ -7,6 +7,25 @@ freed slots are refilled on the very next tick, so the pool stays full
 under load with no lock-step barrier. Time is counted in *decode steps*,
 not wall-clock: arrival processes expressed in step units make scheduling
 decisions (and tests) machine-independent.
+
+What a run did is recorded as it happens, on the host clock
+(``time.perf_counter``), and as spans of the profiler's trace
+(``jax.profiler.TraceAnnotation``, under a microsecond each when no trace
+is being collected):
+
+- ``serve.tick``: a loop turn that steps the pool, from its admissions
+  through its bookkeeping (where the pool stood idle, the admissions that
+  woke it precede the span);
+- ``serve.admit``: one admission, ``prefill`` + ``insert``;
+- ``serve.step``: the dispatch of ``generate_step``;
+- ``serve.read``: the wait for the step's tokens on the host;
+- ``serve.book``: the token appends, the evictions and ``engine.observe``.
+
+:class:`ServeReport` holds one :class:`TickRecords` entry per decode step
+(the k-th ``serve.tick`` span of a trace is its k-th entry) and one
+:class:`RequestRecords` entry per request. A request's time to first token
+is ``ticks.read_s[first_tick] - due_s``, and the gaps between its tokens
+are ``np.diff(ticks.read_s[first_tick:last_tick + 1])``.
 """
 from __future__ import annotations
 
@@ -15,6 +34,7 @@ from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 def poisson_arrivals(n: int, rate_per_step: float, seed: int = 0
@@ -26,21 +46,66 @@ def poisson_arrivals(n: int, rate_per_step: float, seed: int = 0
 
 
 @dataclass
+class TickRecords:
+    """One entry per decode step, in order; times in seconds on
+    ``time.perf_counter``. A tick carries the admissions made since the
+    previous step's tokens reached the host."""
+    start_s: np.ndarray         # the loop's first turn after the previous read
+    read_s: np.ndarray          # the step's tokens on the host
+    admit_s: np.ndarray         # seconds in its serve.admit spans
+    step_s: np.ndarray          # seconds in serve.step (the dispatch)
+    wait_s: np.ndarray          # seconds in serve.read (waiting for tokens)
+    admitted: np.ndarray        # requests admitted
+    prompt_tokens: np.ndarray   # their prompt tokens (no page padding)
+    active: np.ndarray          # slots stepped
+    kv_positions: np.ndarray    # positions the stepped slots attend, summed
+
+    @classmethod
+    def from_rows(cls, rows: list) -> "TickRecords":
+        cols = np.asarray(rows, float).reshape(len(rows), 9).T
+        return cls(*cols[:5], *cols[5:].astype(np.int64))
+
+
+@dataclass
+class RequestRecords:
+    """One entry per request, in input order; times as :class:`TickRecords`.
+    A request done at prefill has no tick (``first_tick == last_tick ==
+    -1``)."""
+    due_s: np.ndarray           # the step counter reached its arrival step
+    admit_s: np.ndarray         # its prefill began
+    first_tick: np.ndarray      # the first decode step that stepped it
+    last_tick: np.ndarray       # the last
+    prompt_len: np.ndarray      # prompt tokens prefilled
+
+
+@dataclass
 class ServeReport:
-    """What a :func:`serve` run did: per-request outputs plus the throughput
-    and occupancy accounting the bench contract is scored on."""
+    """What a :func:`serve` run did: per-request outputs, the tick and
+    request records, and the counts the bench contract is scored on."""
     outputs: List[np.ndarray]          # per request, [max_new] int32
-    n_steps: int                       # decode steps executed
     n_prefills: int
     wall_s: float
     tokens_out: int                    # generated tokens actually requested
-    occupancy_mean: float              # mean occupied slots per decode step
     queue_peak: int                    # max requests waiting for a slot
+    ticks: TickRecords
+    requests: RequestRecords
     session: Optional[object] = None   # the engine's EnergySession, if any
 
     @property
-    def tokens_per_s(self) -> float:
-        return self.tokens_out / max(self.wall_s, 1e-9)
+    def n_steps(self) -> int:
+        """Decode steps executed."""
+        return len(self.ticks.read_s)
+
+    @property
+    def occupancy_mean(self) -> float:
+        """Mean occupied slots per decode step (0 without one)."""
+        return float(self.ticks.active.mean()) if self.n_steps else 0.0
+
+
+def _span(name: str) -> TraceAnnotation:
+    span = TraceAnnotation(name)
+    span.__enter__()
+    return span
 
 
 def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
@@ -67,24 +132,45 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
     partial: List[Optional[List[int]]] = [None] * n
     slot_req = [-1] * S                 # request index occupying each slot
     slot_left = np.zeros(S, np.int64)   # tokens still to generate per slot
+    slot_pos = np.zeros(S, np.int64)    # positions each slot has written
     active = np.zeros(S, bool)
     free = list(range(S))[::-1]
     qi = 0                              # next arrival (in sorted order)
     done = 0
     step = 0
-    occ_sum = 0
-    decode_ticks = 0
     queue_peak = 0
+    rows: list = []                     # TickRecords, one tuple per tick
+    admit_t = np.zeros(n)
+    first_tick = np.full(n, -1, np.int64)
+    last_tick = np.full(n, -1, np.int64)
+    prompt_len = np.zeros(n, np.int64)
     t0 = time.perf_counter()
+    reached, reached_t = [0], [t0]      # step counter values, when reached
+    begun = None                        # the tick under way began
+    n_adm = adm_tokens = 0
+    adm_s = 0.0
     while done < n:
         tick_t0 = time.perf_counter()
+        if begun is None:
+            begun = tick_t0
+        tick = _span("serve.tick") if active.any() else None
         n_pre = 0
         while free and qi < n and arr_sorted[qi] <= step:
             i = int(order[qi])
             qi += 1
             slot = free.pop()
-            pf = engine.prefill(requests[i], temperature)
-            engine.insert(pf, slot)
+            with TraceAnnotation("serve.admit"):
+                a0 = time.perf_counter()
+                pf = engine.prefill(requests[i], temperature)
+                engine.insert(pf, slot)
+                adm_s += time.perf_counter() - a0
+            if step > reached[-1]:      # the pool stood idle: the counter
+                reached.append(step)    # jumped to this arrival
+                reached_t.append(a0)
+            admit_t[i] = a0
+            prompt_len[i] = pf.length
+            n_adm += 1
+            adm_tokens += pf.length
             # keep the first token as a device scalar: forcing it here would
             # serialize every admission on its own B=1 prefill; it is
             # materialized at eviction, when the value is long since ready
@@ -98,14 +184,40 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
             else:
                 slot_req[slot] = i
                 slot_left[slot] = pf.max_new - 1
+                slot_pos[slot] = pf.length
                 active[slot] = True
+                first_tick[i] = len(rows)
         arrived = int(np.searchsorted(arr_sorted, step, side="right"))
         queue_peak = max(queue_peak, arrived - qi)
-        if active.any():
-            toks = np.asarray(engine.generate_step(active))
-            occ_sum += int(active.sum())
-            decode_ticks += 1
-            for s in np.flatnonzero(active):
+        if not active.any():
+            if n_pre:
+                engine.observe(n_pre, 0,
+                               wall_s=time.perf_counter() - tick_t0)
+            if done < n and qi < n:
+                # pool idle until the next arrival: skip the dead time
+                step = max(step + 1, int(np.ceil(arr_sorted[qi])))
+            continue
+        if tick is None:                # woken from idle by its admissions
+            tick = _span("serve.tick")
+        with TraceAnnotation("serve.step"):
+            s0 = time.perf_counter()
+            pending = engine.generate_step(active)
+            s1 = time.perf_counter()
+        with TraceAnnotation("serve.read"):
+            toks = np.asarray(pending)
+            t_read = time.perf_counter()
+        with TraceAnnotation("serve.book"):
+            stepped = np.flatnonzero(active)
+            # a slot at position p writes p and attends p + 1 positions
+            kv = int(slot_pos[stepped].sum()) + len(stepped)
+            slot_pos[stepped] += 1
+            rows.append((begun, t_read, adm_s, s1 - s0, t_read - s1, n_adm,
+                         adm_tokens, len(stepped), kv))
+            step += 1
+            reached.append(step)
+            reached_t.append(t_read)
+            begun, n_adm, adm_tokens, adm_s = None, 0, 0, 0.0
+            for s in stepped:
                 i = slot_req[s]
                 partial[i].append(int(toks[s]))
                 slot_left[s] -= 1
@@ -115,21 +227,19 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
                     free.append(int(s))
                     outputs[i] = np.asarray([int(v) for v in partial[i]],
                                             np.int32)
+                    last_tick[i] = len(rows) - 1
                     done += 1
             engine.observe(n_pre, 1,
                            wall_s=time.perf_counter() - tick_t0)
-            step += 1
-        else:
-            if n_pre:
-                engine.observe(n_pre, 0,
-                               wall_s=time.perf_counter() - tick_t0)
-            if done < n and qi < n:
-                # pool idle until the next arrival: skip the dead time
-                step = max(step + 1, int(np.ceil(arr_sorted[qi])))
+        tick.__exit__(None, None, None)
     wall_s = time.perf_counter() - t0
+    # due: the counter's first value at or past the arrival step
+    due = np.asarray(reached_t)[np.searchsorted(reached, arr, side="left")]
     return ServeReport(
-        outputs=outputs, n_steps=decode_ticks, n_prefills=engine.n_prefills,
-        wall_s=wall_s, tokens_out=int(sum(len(o) for o in outputs)),
-        occupancy_mean=occ_sum / max(decode_ticks, 1),
-        queue_peak=queue_peak,
+        outputs=outputs, n_prefills=engine.n_prefills, wall_s=wall_s,
+        tokens_out=int(sum(len(o) for o in outputs)), queue_peak=queue_peak,
+        ticks=TickRecords.from_rows(rows),
+        requests=RequestRecords(due_s=due, admit_s=admit_t,
+                                first_tick=first_tick, last_tick=last_tick,
+                                prompt_len=prompt_len),
         session=getattr(engine, "session", None))

@@ -35,7 +35,6 @@ class _FakeEngine:
         self.max_slots, self.max_len = max_slots, max_len
         self.session = None
         self.n_prefills = 0
-        self.n_steps = 0
         self.left = [0] * max_slots        # tokens still owed per slot
         self.occupant = [-1] * max_slots
         self.count = [0] * max_slots
@@ -63,7 +62,6 @@ class _FakeEngine:
                 self.count[s] += 1
                 self.left[s] -= 1
                 toks[s] = self.occupant[s] * 1000 + self.count[s]
-        self.n_steps += 1
         return toks
 
     def observe(self, n_prefills, n_decode=1, wall_s=None):
@@ -99,8 +97,12 @@ def test_scheduler_slot_invariants(data):
         assert out.tolist() == _expected_output(i, lens[i], budgets[i],
                                                 eng.max_len)
     assert rep.tokens_out == sum(len(o) for o in rep.outputs)
-    if n:
+    # occupancy is a mean over decode steps: a run where every request is
+    # done at prefill takes none
+    if rep.n_steps > 0:
         assert 0 < rep.occupancy_mean <= slots
+    else:
+        assert rep.occupancy_mean == 0
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])

@@ -10,7 +10,6 @@ of this file, in the worker that runs it.
 import dataclasses
 import functools
 import os
-from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +24,7 @@ from repro.kernels.vai import vai
 from repro.models import decode as decode_mod
 from repro.models import model as model_mod
 from repro.models.transformer import Runtime
-from repro.serving.engine import ContinuousEngine
+from repro.serving.engine import decode_step
 from repro.tuning import FlashAttentionSpace, MembwSpace, VaiSpace
 
 MiB = 1 << 20
@@ -131,8 +130,7 @@ def test_stablelm_decode_step_compiles_at_full_width(one_chip):
         jax.eval_shape(lambda: decode_mod.init_decode_state(
             cfg, rt, slots, max_len)))
     vec = functools.partial(_sds, one_chip, (slots,))
-    step = jax.jit(functools.partial(ContinuousEngine._step_impl,
-                                     SimpleNamespace(cfg=cfg, rt=rt)),
+    step = jax.jit(functools.partial(decode_step, cfg, rt),
                    donate_argnums=(1, 2, 3, 6))
     compiled = step.lower(params, state, vec(jnp.int32), vec(jnp.int32),
                           vec(jnp.float32), vec(jnp.bool_), key).compile()
