@@ -333,53 +333,68 @@ def _dense_decode_attend(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, 1, q.shape[2], v.shape[-1])
 
 
-def _batch_scatter(cache: jax.Array, new: jax.Array,
-                   slot: jax.Array) -> jax.Array:
-    """Per-sequence cache write: cache [B,S,...], new [B,1,...], slot [B]."""
-    return jax.vmap(
-        lambda c, u, s: jax.lax.dynamic_update_slice_in_dim(
-            c, u.astype(c.dtype), s, axis=0))(cache, new, slot)
+def _rope_positions(pos: jax.Array) -> jax.Array:
+    """Rope positions of the one new token: ``[B, 1]`` for per-sequence
+    ``pos [B]``, ``[1, 1]`` for a lock-step scalar."""
+    pos = pos.astype(jnp.int32)
+    return pos[:, None] if pos.ndim == 1 else pos.reshape(1, 1)
+
+
+def _write_rows(cache: jax.Array, new: jax.Array, slot: jax.Array,
+                layer: Optional[jax.Array]) -> jax.Array:
+    """Write each sequence's new row in place. ``cache`` is one layer's
+    ``[B, S, ...]``, or with ``layer`` the stack ``[L, B, S, ...]``; ``new``
+    is ``[B, 1, ...]``; ``slot`` a scalar or per-sequence ``[B]``. One
+    scatter of B rows, whatever the cache's size."""
+    B = new.shape[0]
+    at = (jnp.arange(B), jnp.broadcast_to(slot, (B,)))
+    if layer is not None:
+        at = (layer,) + at
+    return cache.at[at].set(new[:, 0].astype(cache.dtype))
+
+
+def _layer_view(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
+    """The ``[B, S, ...]`` cache that attention reads."""
+    return cache if layer is None else cache[layer]
 
 
 def decode_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
                           cache: Dict, pos: jax.Array, window: int = 0,
-                          use_rope: bool = True,
-                          impl: str = "chunked") -> Tuple[jax.Array, Dict]:
-    """One-token decode. ``pos`` is the absolute position — a scalar for
-    lock-step batches, or a per-sequence ``[B]`` vector (slot-pool decode:
-    each sequence ropes, writes and masks at its own position, so batch-mates
-    of different lengths never see each other's padding). Keys are roped at
-    write time; local attention uses a ring buffer of ``window``."""
-    per_seq = pos.ndim == 1
+                          use_rope: bool = True, impl: str = "chunked",
+                          layer: Optional[jax.Array] = None
+                          ) -> Tuple[jax.Array, Dict]:
+    """One-token decode: rope the new token's K/V, write them into the cache
+    in place, attend over the cache. ``pos`` is the absolute position — a
+    scalar for lock-step batches, or a per-sequence ``[B]`` vector
+    (slot-pool decode: each sequence ropes, writes and masks at its own
+    position, so batch-mates of different lengths never see each other's
+    padding). ``cache`` holds one layer's ``k``/``v`` ``[B, S, Hkv, hd]``,
+    or with ``layer`` the whole stack ``[L, B, S, Hkv, hd]``, of which only
+    that layer's rows are written and read (the stack stays in the layer
+    scan's carry). Keys are roped at write time; local attention uses a
+    ring buffer of ``window``."""
     q, k, v = _qkv(p, x)                      # [B, 1, H(kv), hd]
     if use_rope:
-        if per_seq:
-            posm = pos.astype(jnp.int32)[:, None]            # [B, 1]
-        else:
-            posv = pos[None] if pos.ndim == 0 else pos
-            posm = posv.astype(jnp.int32)[None, :]           # [1, 1]
+        posm = _rope_positions(pos)
         q = apply_rope(q, posm, cfg.rope_theta)
         k = apply_rope(k, posm, cfg.rope_theta)
-    slots = cache["k"].shape[1]
+    slots = cache["k"].shape[-3]
     slot = (pos % slots).astype(jnp.int32)
-    if per_seq:
-        ck = _batch_scatter(cache["k"], k, slot)
-        cv = _batch_scatter(cache["v"], v, slot)
-    else:
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+    new = {"k": _write_rows(cache["k"], k, slot, layer),
+           "v": _write_rows(cache["v"], v, slot, layer)}
+    ck, cv = _layer_view(new["k"], layer), _layer_view(new["v"], layer)
     # Ring semantics: every written slot is within the window by construction,
     # so masking only needs "slot has been written": slot_idx <= pos.
     valid = jnp.minimum(pos + 1, slots)
     scale = cfg.resolved_head_dim ** -0.5
-    if impl == "dense" or per_seq:
+    if impl == "dense" or pos.ndim == 1:
         # per-sequence valid lengths need the batched mask: dense only
         out = _dense_decode_attend(q, ck, cv, valid, scale)
     else:
         out = chunked_attention(q, ck, cv, causal=False, kv_valid_len=valid,
                                 softmax_scale=scale)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, {"k": ck, "v": cv}
+    return y, new
 
 
 # ---------------------------------------------------------------------------
@@ -459,27 +474,22 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array, cache: Dict,
-               pos: jax.Array) -> Tuple[jax.Array, Dict]:
+               pos: jax.Array, layer: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, Dict]:
     """Absorbed-matrix MLA decode: attention runs entirely in the latent
     space — the cache stores only (c_kv, k_rope) per token (the paper-scale
     memory win of MLA). ``pos`` is a scalar, or a per-sequence ``[B]``
-    vector for slot-pool decode."""
-    per_seq = pos.ndim == 1
-    if per_seq:
-        posm = pos.astype(jnp.int32)[:, None]                # [B, 1]
-    else:
-        posm = (pos[None] if pos.ndim == 0 else pos).astype(jnp.int32)[None, :]
+    vector for slot-pool decode. ``cache`` holds one layer's ``[B, S, *]``
+    latents, or with ``layer`` the stack ``[L, B, S, *]``, written in place
+    at that layer's rows as in :func:`decode_self_attention`."""
+    posm = _rope_positions(pos)
     q_nope, q_rope = _mla_q(p, cfg, x, posm)         # [B,1,H,*]
     c_new, kr_new = _mla_latent(p, cfg, x, posm)     # [B,1,r], [B,1,rope]
-    if per_seq:
-        slot = pos.astype(jnp.int32)
-        ck = _batch_scatter(cache["c_kv"], c_new, slot)
-        kr = _batch_scatter(cache["k_rope"], kr_new, slot)
-    else:
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache["c_kv"], c_new.astype(cache["c_kv"].dtype), pos, axis=1)
-        kr = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), pos, axis=1)
+    slot = (pos % cache["c_kv"].shape[-2]).astype(jnp.int32)
+    new = {"c_kv": _write_rows(cache["c_kv"], c_new, slot, layer),
+           "k_rope": _write_rows(cache["k_rope"], kr_new, slot, layer)}
+    ck = _layer_view(new["c_kv"], layer)
+    kr = _layer_view(new["k_rope"], layer)
     # absorb W_uk into q: q_tilde = q_nope @ W_uk^T  -> latent space
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"])
     scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
@@ -488,7 +498,7 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array, cache: Dict,
     s = (jnp.einsum("bshr,btr->bhst", q_lat, ck.astype(q_lat.dtype))
          + jnp.einsum("bshk,btk->bhst", q_rope, kr.astype(q_rope.dtype)))
     s = (s.astype(jnp.float32) * scale)
-    if per_seq:
+    if pos.ndim == 1:
         s = jnp.where((kv_pos[None, :] < valid[:, None])[:, None, None, :],
                       s, NEG_INF)
     else:
@@ -497,4 +507,4 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array, cache: Dict,
     o_lat = jnp.einsum("bhst,btr->bshr", a.astype(ck.dtype), ck)
     out = jnp.einsum("bshr,rhk->bshk", o_lat, p["wv_b"])
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, {"c_kv": ck, "k_rope": kr}
+    return y, new

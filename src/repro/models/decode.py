@@ -2,9 +2,11 @@
 for every architecture family.
 
 State layout mirrors the trunk structure (stacked over layers / pattern
-groups) so decode steps scan over (params, cache) jointly. The same builder
-runs in "spec mode" to produce the PartitionSpec tree used by the dry-run and
-the serving launcher.
+groups). The dense and MoE decode step carries the stacked cache through its
+layer scan and writes each step's new rows in place, so the step moves B
+rows of it, not the whole stack; the other families scan over (params,
+cache) jointly. The same builder runs in "spec mode" to produce the
+PartitionSpec tree used by the dry-run and the serving launcher.
 """
 from __future__ import annotations
 
@@ -355,22 +357,27 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
     pos = pos.astype(jnp.int32)
 
     if cfg.family in ("dense", "moe"):
+        # the stacked cache rides in the carry: each layer writes its B new
+        # rows in place and attends over its own view of the stack
         def body(carry, inp):
-            h = carry
-            p_layer, cache = inp
+            h, cache = carry
+            layer, p_layer = inp
             z = rms_norm(h, p_layer["ln1"], cfg.norm_eps)
             if cfg.use_mla:
-                y, new = attn.mla_decode(p_layer["attn"], cfg, z, cache, pos)
+                y, cache = attn.mla_decode(p_layer["attn"], cfg, z, cache,
+                                           pos, layer=layer)
             else:
-                y, new = attn.decode_self_attention(p_layer["attn"], cfg, z,
-                                                    cache, pos,
-                                                    impl=rt.decode_impl)
+                y, cache = attn.decode_self_attention(
+                    p_layer["attn"], cfg, z, cache, pos,
+                    impl=rt.decode_impl, layer=layer)
             h = h + y
             y2, _ = tfm._ffn(p_layer, cfg, rt, h, decode=True)
-            return h + y2, new
+            return (h + y2, cache), None
 
-        x, new_layers = jax.lax.scan(body, x, (p["layers"], state["layers"]))
-        state = {"layers": new_layers}
+        (x, layers), _ = jax.lax.scan(
+            body, (x, state["layers"]),
+            (jnp.arange(cfg.n_layers, dtype=jnp.int32), p["layers"]))
+        state = {"layers": layers}
 
     elif cfg.family == "ssm":
         def body(carry, inp):

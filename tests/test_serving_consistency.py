@@ -2,21 +2,22 @@
 reproduce the teacher-forced forward logits for EVERY architecture family
 (KV caches, MLA latent cache, SSM state, RG-LRU state, ring buffers,
 cross-attention caches all exercised)."""
-import dataclasses
+import functools
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from conftest import reduced_f32
 from repro.configs import ARCH_IDS
+from repro.models import attention as A
 from repro.models import decode as D
 from repro.models import model as M
+from repro.models import transformer as T
+from repro.models.common import apply_rope, rms_norm
 from repro.models.transformer import Runtime
-
-# long-running model/serving tests: fast lane skips these
-pytestmark = pytest.mark.slow
 
 B, S = 2, 16
 
@@ -31,6 +32,7 @@ def _no_moe_capacity_drops(monkeypatch):
     monkeypatch.setattr(moe_mod, "CAPACITY_FACTOR", 8.0)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_decode_matches_forward(arch):
     cfg = reduced_f32(arch)
@@ -57,6 +59,149 @@ def test_decode_matches_forward(arch):
                                    rtol=2e-4, atol=2e-4)
 
 
+def _reference_attention(p, cfg, z, pos, cache, layer):
+    """One layer's decode attention, one sequence at a time: write the new
+    row at ``pos[b]``, then attend over positions ``0..pos[b]`` in float64.
+    GQA expands the kv heads; MLA decompresses per-head keys and values from
+    the latent (the program absorbs the up-projections instead)."""
+    posm = pos[:, None]
+    if cfg.use_mla:
+        q_nope, q_rope = A._mla_q(p, cfg, z, posm)
+        c_new, kr_new = A._mla_latent(p, cfg, z, posm)
+        q = np.concatenate([np.asarray(q_nope), np.asarray(q_rope)], -1)
+        rows = {"c_kv": c_new, "k_rope": kr_new}
+        scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    else:
+        q, k, v = A._qkv(p, z)
+        q = np.asarray(apply_rope(q, posm, cfg.rope_theta))
+        rows = {"k": apply_rope(k, posm, cfg.rope_theta), "v": v}
+        scale = cfg.resolved_head_dim ** -0.5
+    for name, row in rows.items():
+        for b in range(len(pos)):
+            cache[name][layer, b, pos[b]] = np.asarray(row)[b, 0]
+    outs = []
+    for b in range(len(pos)):
+        live = slice(0, pos[b] + 1)
+        if cfg.use_mla:
+            c = cache["c_kv"][layer, b, live].astype(np.float64)
+            kr = cache["k_rope"][layer, b, live].astype(np.float64)
+            k_nope = np.einsum("tr,rhk->thk", c, np.asarray(p["wk_b"]))
+            keys = np.concatenate(
+                [k_nope, np.broadcast_to(kr[:, None], k_nope.shape[:2]
+                                         + kr.shape[-1:])], -1)
+            vals = np.einsum("tr,rhk->thk", c, np.asarray(p["wv_b"]))
+        else:
+            G = q.shape[2] // cache["k"].shape[3]
+            keys = np.repeat(cache["k"][layer, b, live], G, axis=1)
+            vals = np.repeat(cache["v"][layer, b, live], G, axis=1)
+        s = np.einsum("hd,thd->ht", q[b, 0].astype(np.float64), keys) * scale
+        w = np.exp(s - s.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        outs.append(np.einsum("ht,thd->hd", w, vals))
+    out = np.stack(outs)[:, None].astype(np.float32)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+
+
+def _reference_decode(cfg, rt, params, tokens, pos, cache):
+    """The decode step as a plain Python loop over the layers, writing into
+    the numpy ``cache`` in place."""
+    x = M.embed(params, cfg, tokens[:, None])
+    for layer in range(cfg.n_layers):
+        p = jax.tree.map(lambda a: a[layer], params["layers"])
+        z = rms_norm(x, p["ln1"], cfg.norm_eps)
+        x = x + _reference_attention(p["attn"], cfg, z, pos, cache, layer)
+        y, _ = T._ffn(p, cfg, rt, x, decode=True)
+        x = x + y
+    return M.logits_fn(params, cfg, x)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-12b", "deepseek-v3-671b"])
+def test_slot_pool_decode_matches_a_loop_over_layers(arch):
+    """Slot-pool decode (per-slot positions, one slot inactive, several
+    steps) gives the logits and every cache row of a plain loop over the
+    layers: the new rows land at ``(layer, slot, pos % max_len)``, every
+    other row is left as it was, and stale rows past a slot's position
+    are masked."""
+    cfg = reduced_f32(arch)
+    rt = Runtime(tp=1, moe_impl="local")
+    key = jax.random.PRNGKey(3)
+    params, _ = M.init_params(cfg, rt, key)
+    slots, max_len = 4, 24
+    # stale rows everywhere: a slot may only ever see its own live rows
+    state = jax.tree.map(
+        lambda a: jax.random.normal(key, a.shape, a.dtype),
+        D.init_decode_state(cfg, rt, slots, max_len))
+    cache = jax.tree.map(np.array, state["layers"])
+    pos = jnp.array([3, 17, 0, 9], jnp.int32)
+    active = jnp.array([True, True, False, True])
+    tokens = jax.random.randint(key, (slots,), 0, cfg.vocab_size)
+    step = jax.jit(functools.partial(D.decode_step, cfg, rt))
+    for _ in range(3):
+        written = np.zeros(cache[next(iter(cache))].shape[:3], bool)
+        written[:, np.arange(slots), np.asarray(pos)] = True
+        before = jax.tree.map(np.asarray, state["layers"])
+        logits, state = step(params, tokens[:, None], pos, state)
+        want = _reference_decode(cfg, rt, params, tokens, np.asarray(pos),
+                                 cache)
+        np.testing.assert_allclose(logits, want, rtol=2e-4, atol=2e-4)
+        for name, got in state["layers"].items():
+            got = np.asarray(got)
+            np.testing.assert_array_equal(got[~written],
+                                          before[name][~written])
+            np.testing.assert_allclose(got[written], cache[name][written],
+                                       rtol=2e-5, atol=2e-5)
+        pos = pos + active.astype(jnp.int32)
+        tokens = jnp.where(active, jnp.argmax(logits[:, 0], -1), tokens)
+
+
+def _scans(jaxpr):
+    """Every scan equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _scans(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _scans(sub)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-12b", "dbrx-132b",
+                                  "deepseek-v3-671b"])
+def test_decode_step_carries_the_stacked_cache(arch):
+    """The stacked cache goes into the layer scan as carry and comes out as
+    carry, untouched on the way: no scan takes it as xs (a per-layer slice
+    copied out) or gives it back as ys (a fresh stack written), the pattern
+    that moved the whole cache several times a step."""
+    cfg = reduced_f32(arch)
+    rt = Runtime(tp=1, moe_impl="local")
+    params = jax.eval_shape(
+        lambda k: M.init_params(cfg, rt, k)[0], jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda: D.init_decode_state(cfg, rt, 3, 40))
+    tok = jax.ShapeDtypeStruct((3, 1), jnp.int32)
+    pos = jax.ShapeDtypeStruct((3,), jnp.int32)
+    closed = jax.make_jaxpr(functools.partial(D.decode_step, cfg, rt))(
+        params, tok, pos, state)
+    jaxpr = closed.jaxpr
+    n_state = len(jax.tree.leaves(state))
+    stacks = {leaf.shape for leaf in jax.tree.leaves(state)}
+    state_in, state_out = jaxpr.invars[-n_state:], jaxpr.outvars[1:]
+
+    layer_scans = []
+    for eqn in _scans(jaxpr):
+        n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = eqn.invars[n_const + n_carry:]
+        ys = eqn.outvars[n_carry:]
+        assert not any(v.aval.shape in stacks for v in xs + ys), eqn
+        carry_in = eqn.invars[n_const:n_const + n_carry]
+        if set(state_in) <= set(carry_in):
+            layer_scans.append(eqn)
+            assert set(state_out) <= set(eqn.outvars[:n_carry])
+    assert len(layer_scans) == 1
+
+
+@pytest.mark.slow
 def test_serve_engine_greedy_consistency():
     from repro.serving import Request, ServeEngine
     cfg = reduced_f32("stablelm-12b")
@@ -73,6 +218,7 @@ def test_serve_engine_greedy_consistency():
     np.testing.assert_array_equal(outs[0], outs2[0])
 
 
+@pytest.mark.slow
 def test_serve_engine_heterogeneous_prompts_not_truncated():
     """Prompts are right-padded to the batch max, not silently truncated to
     the first request's length: a long prompt decodes identically whether
@@ -103,6 +249,7 @@ def test_serve_engine_heterogeneous_prompts_not_truncated():
     np.testing.assert_array_equal(mixed[1], blocking[1])
 
 
+@pytest.mark.slow
 def test_serve_engine_session_telemetry():
     """The engine records one telemetry sample per decode step through its
     EnergySession."""
