@@ -154,6 +154,11 @@ def main() -> None:
     print(f"{len(reqs)} requests  {rep.tokens_out} tokens  "
           f"{rep.n_steps} decode steps  occupancy {rep.occupancy_mean:.2f}"
           f"  wall {rep.wall_s:.2f} s")
+    if rep.n_steps:
+        read = rep.ticks.kv_read_positions.sum()
+        print(f"decode attention read {read} kv positions, "
+              f"{100 * rep.ticks.kv_positions.sum() / read:.1f}% of them "
+              f"live")
     for name, ms in latencies_ms(rep).items():
         if ms.size:
             p50, p95 = np.percentile(ms, [50, 95])

@@ -4,7 +4,9 @@ replicate-or-pad head policy, MLA (DeepSeek-V3) with absorbed decode, local
 
 The chunked implementation is the XLA-native path used for training and the
 multi-pod dry-run; the Pallas flash kernel (``repro.kernels.flash_attention``)
-is the TPU fast path validated against the same reference.
+is the TPU fast path validated against the same reference. Slot-pool decode
+over the stacked cache runs the Pallas pool kernel
+(``repro.kernels.decode_attention``) on a TPU.
 """
 from __future__ import annotations
 
@@ -358,10 +360,30 @@ def _layer_view(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
     return cache if layer is None else cache[layer]
 
 
+def _on_tpu() -> bool:
+    """Whether the program runs on a TPU, the backend the pool kernel is
+    compiled for (elsewhere the Pallas interpreter runs it)."""
+    return jax.default_backend() == "tpu"
+
+
+def pool_kernel_chunk(cfg: ModelConfig, tp: int, max_len: int) -> int:
+    """The kv chunk in which slot-pool decode attention over the stacked
+    cache of ``max_len`` positions reads each slot's written positions
+    through the pool kernel, or 0 where it reads every position of the
+    layer's view instead: MLA, a sequence or head axis sharded over
+    ``tp > 1``, a backend other than a TPU, or heads no block of the kernel
+    tiles within its VMEM."""
+    if cfg.use_mla or tp != 1 or not _on_tpu():
+        return 0
+    from repro.kernels.decode_attention import kv_chunk
+    return kv_chunk(max_len, cfg.n_heads, cfg.n_kv_heads,
+                    cfg.resolved_head_dim, jnp.dtype(cfg.dtype).itemsize)
+
+
 def decode_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
                           cache: Dict, pos: jax.Array, window: int = 0,
                           use_rope: bool = True, impl: str = "chunked",
-                          layer: Optional[jax.Array] = None
+                          layer: Optional[jax.Array] = None, tp: int = 1
                           ) -> Tuple[jax.Array, Dict]:
     """One-token decode: rope the new token's K/V, write them into the cache
     in place, attend over the cache. ``pos`` is the absolute position — a
@@ -372,7 +394,10 @@ def decode_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
     or with ``layer`` the whole stack ``[L, B, S, Hkv, hd]``, of which only
     that layer's rows are written and read (the stack stays in the layer
     scan's carry). Keys are roped at write time; local attention uses a
-    ring buffer of ``window``."""
+    ring buffer of ``window``. Per-sequence ``pos`` over the stack with
+    global attention reads each sequence's live chunks of that layer straight
+    from the stack through the pool kernel where :func:`pool_kernel_chunk`
+    gives a chunk; elsewhere attention reads the layer's view."""
     q, k, v = _qkv(p, x)                      # [B, 1, H(kv), hd]
     if use_rope:
         posm = _rope_positions(pos)
@@ -382,17 +407,25 @@ def decode_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
     slot = (pos % slots).astype(jnp.int32)
     new = {"k": _write_rows(cache["k"], k, slot, layer),
            "v": _write_rows(cache["v"], v, slot, layer)}
-    ck, cv = _layer_view(new["k"], layer), _layer_view(new["v"], layer)
     # Ring semantics: every written slot is within the window by construction,
     # so masking only needs "slot has been written": slot_idx <= pos.
     valid = jnp.minimum(pos + 1, slots)
     scale = cfg.resolved_head_dim ** -0.5
-    if impl == "dense" or pos.ndim == 1:
-        # per-sequence valid lengths need the batched mask: dense only
-        out = _dense_decode_attend(q, ck, cv, valid, scale)
+    chunk = (pool_kernel_chunk(cfg, tp, slots)
+             if pos.ndim == 1 and layer is not None and not window else 0)
+    if chunk:
+        from repro.kernels.decode_attention import pool_decode_attention
+        out = pool_decode_attention(
+            q, new["k"], new["v"], layer, valid, scale=scale, chunk=chunk,
+            interpret=jax.default_backend() != "tpu")
     else:
-        out = chunked_attention(q, ck, cv, causal=False, kv_valid_len=valid,
-                                softmax_scale=scale)
+        ck, cv = _layer_view(new["k"], layer), _layer_view(new["v"], layer)
+        if impl == "dense" or pos.ndim == 1:
+            # per-sequence valid lengths need the batched mask: dense only
+            out = _dense_decode_attend(q, ck, cv, valid, scale)
+        else:
+            out = chunked_attention(q, ck, cv, causal=False,
+                                    kv_valid_len=valid, softmax_scale=scale)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new
 

@@ -358,7 +358,7 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
 
     if cfg.family in ("dense", "moe"):
         # the stacked cache rides in the carry: each layer writes its B new
-        # rows in place and attends over its own view of the stack
+        # rows in place and attends over its rows of the stack
         def body(carry, inp):
             h, cache = carry
             layer, p_layer = inp
@@ -369,7 +369,7 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
             else:
                 y, cache = attn.decode_self_attention(
                     p_layer["attn"], cfg, z, cache, pos,
-                    impl=rt.decode_impl, layer=layer)
+                    impl=rt.decode_impl, layer=layer, tp=rt.tp)
             h = h + y
             y2, _ = tfm._ffn(p_layer, cfg, rt, h, decode=True)
             return (h + y2, cache), None

@@ -37,6 +37,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import roofline
 from repro.core.hardware import ChipSpec, TPU_V5E
 from repro.power import ChipModel, EnergySession, StepProfile
+from repro.models import attention as attn
 from repro.models import decode as decode_mod
 from repro.models.transformer import Runtime
 
@@ -191,6 +192,9 @@ class ContinuousEngine:
             functools.partial(decode_step, cfg, rt), decode_step)
         self._step_fn = jax.jit(step, donate_argnums=donate)
         self.n_prefills = 0
+        # the chunk in which the step's attention reads each slot's written
+        # positions (the pool kernel), or 0: it reads all max_len of them
+        self.kv_chunk = attn.pool_kernel_chunk(cfg, rt.tp, max_len)
 
     # ------------------------------------------------------------- prefill
     def _bucket(self, length: int) -> int:

@@ -59,10 +59,11 @@ class TickRecords:
     prompt_tokens: np.ndarray   # their prompt tokens (no page padding)
     active: np.ndarray          # slots stepped
     kv_positions: np.ndarray    # positions the stepped slots attend, summed
+    kv_read_positions: np.ndarray   # positions the step's attention read
 
     @classmethod
     def from_rows(cls, rows: list) -> "TickRecords":
-        cols = np.asarray(rows, float).reshape(len(rows), 9).T
+        cols = np.asarray(rows, float).reshape(len(rows), 10).T
         return cls(*cols[:5], *cols[5:].astype(np.int64))
 
 
@@ -102,6 +103,16 @@ class ServeReport:
         return float(self.ticks.active.mean()) if self.n_steps else 0.0
 
 
+def _kv_read(pos: np.ndarray, max_len: int, chunk: int) -> int:
+    """Positions a decode step's attention reads with every slot at
+    ``pos``: each slot's extent rounded up to ``chunk``, or with ``chunk``
+    0 all ``max_len`` positions of every slot."""
+    if not chunk:
+        return len(pos) * max_len
+    extent = np.minimum(pos + 1, max_len)
+    return int((-(-extent // chunk)).sum()) * chunk
+
+
 def _span(name: str) -> TraceAnnotation:
     span = TraceAnnotation(name)
     span.__enter__()
@@ -128,11 +139,13 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
     arr_sorted = arr[order]
 
     S = engine.max_slots
+    # an engine without ``kv_chunk`` reads every position of every slot
+    chunk = getattr(engine, "kv_chunk", 0)
     outputs: List[Optional[np.ndarray]] = [None] * n
     partial: List[Optional[List[int]]] = [None] * n
     slot_req = [-1] * S                 # request index occupying each slot
     slot_left = np.zeros(S, np.int64)   # tokens still to generate per slot
-    slot_pos = np.zeros(S, np.int64)    # positions each slot has written
+    slot_pos = np.zeros(S, np.int64)    # the engine's position of each slot
     active = np.zeros(S, bool)
     free = list(range(S))[::-1]
     qi = 0                              # next arrival (in sorted order)
@@ -164,6 +177,7 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
                 pf = engine.prefill(requests[i], temperature)
                 engine.insert(pf, slot)
                 adm_s += time.perf_counter() - a0
+            slot_pos[slot] = pf.length
             if step > reached[-1]:      # the pool stood idle: the counter
                 reached.append(step)    # jumped to this arrival
                 reached_t.append(a0)
@@ -184,7 +198,6 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
             else:
                 slot_req[slot] = i
                 slot_left[slot] = pf.max_new - 1
-                slot_pos[slot] = pf.length
                 active[slot] = True
                 first_tick[i] = len(rows)
         arrived = int(np.searchsorted(arr_sorted, step, side="right"))
@@ -210,9 +223,10 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
             stepped = np.flatnonzero(active)
             # a slot at position p writes p and attends p + 1 positions
             kv = int(slot_pos[stepped].sum()) + len(stepped)
+            read = _kv_read(slot_pos, engine.max_len, chunk)
             slot_pos[stepped] += 1
             rows.append((begun, t_read, adm_s, s1 - s0, t_read - s1, n_adm,
-                         adm_tokens, len(stepped), kv))
+                         adm_tokens, len(stepped), kv, read))
             step += 1
             reached.append(step)
             reached_t.append(t_read)

@@ -177,6 +177,61 @@ def test_ttft_and_gaps_match_the_proxy(clock, seed):
         assert np.all(r.admit_s[mine] - r.due_s[mine] <= ttft)
 
 
+@pytest.mark.parametrize("chunk,want", [
+    # every position of both slots, whatever their lengths
+    (0, [2 * 64] * 3),
+    # each slot's extent (pos + 1) in chunks of 4: tick 0 at 3 and 5, tick
+    # 1 at 4 and 4 (r2 in r1's slot), tick 2 the stale slot 0 at 5 and r4
+    # at 6 (after r3, done at prefill, passed through slot 1 at 2)
+    (4, [4 + 8, 8 + 8, 8 + 8]),
+])
+def test_kv_read_positions(clock, chunk, want):
+    """What the step's attention read: with the engine's ``kv_chunk`` each
+    slot's extent rounded up to it, inactive slots included (the pool
+    kernel reads them too); without, ``slots x max_len``."""
+    eng = _ClockedEngine(clock, max_slots=2)
+    if chunk:
+        eng.kv_chunk = chunk
+    rep = serve(eng, _requests(LENS, BUDGETS), arrivals=ARRIVALS)
+    np.testing.assert_array_equal(rep.ticks.kv_read_positions, want)
+    np.testing.assert_array_equal(rep.ticks.kv_positions, [10, 10, 7])
+    assert rep.ticks.kv_read_positions.dtype == np.int64
+
+
+def test_engine_reads_live_chunks_with_the_pool_kernel(monkeypatch):
+    """A real engine: attending over layer views its step reads every
+    position; with the pool kernel (interpreted here) it reads whole
+    chunks of 128 covering what each slot wrote, and serves the same
+    tokens."""
+    import dataclasses
+
+    import jax
+
+    from repro.models import attention as A
+    from repro.models import model as M
+    from repro.models.transformer import Runtime
+    from repro.serving import ContinuousEngine
+    cfg = dataclasses.replace(reduced_f32("stablelm-12b"), n_layers=1)
+    rt = Runtime(tp=1)
+    params, _ = M.init_params(cfg, rt, jax.random.PRNGKey(0))
+    reqs = [Request(np.arange(1, n + 1, dtype=np.int32), max_new_tokens=m)
+            for n, m in [(5, 3), (140, 4), (3, 1), (20, 3)]]
+    reports = []
+    for kernel in (False, True):
+        monkeypatch.setattr(A, "_on_tpu", lambda: kernel)
+        eng = ContinuousEngine(cfg, rt, params, max_slots=3, max_len=640)
+        reports.append((eng.kv_chunk, serve(eng, reqs, arrivals=[0, 0, 1, 2])))
+    (chunk_view, view), (chunk, kern) = reports
+    assert chunk_view == 0 and chunk == 128
+    np.testing.assert_array_equal(view.ticks.kv_read_positions, 3 * 640)
+    read = kern.ticks.kv_read_positions
+    assert np.all(read % chunk == 0)
+    assert np.all(kern.ticks.kv_positions <= read)
+    assert np.all(read < 3 * 640)
+    for a, b in zip(view.outputs, kern.outputs):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_launcher_latencies_from_records(worked):
     from repro.launch.serve import latencies_ms
     got = latencies_ms(worked[1])

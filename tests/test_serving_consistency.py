@@ -115,24 +115,43 @@ def _reference_decode(cfg, rt, params, tokens, pos, cache):
     return M.logits_fn(params, cfg, x)
 
 
-@pytest.mark.parametrize("arch", ["stablelm-12b", "deepseek-v3-671b"])
-def test_slot_pool_decode_matches_a_loop_over_layers(arch):
+@pytest.fixture
+def pool_kernel(monkeypatch):
+    """Select the pool kernel on the CPU, in the Pallas interpreter."""
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+
+
+#: the pool kernel's cases read 640 positions in chunks of 128, and start
+#: slots either side of a chunk edge and three steps short of the end
+_KERNEL_CASE = dict(max_len=640, pos=[127, 637, 0, 255])
+
+
+@pytest.mark.parametrize("arch,kernel", [
+    pytest.param("stablelm-12b", False, id="stablelm-12b"),
+    pytest.param("deepseek-v3-671b", False, id="deepseek-v3-671b"),
+    pytest.param("stablelm-12b", True, id="stablelm-12b-pool-kernel"),
+])
+def test_slot_pool_decode_matches_a_loop_over_layers(arch, kernel, request):
     """Slot-pool decode (per-slot positions, one slot inactive, several
     steps) gives the logits and every cache row of a plain loop over the
     layers: the new rows land at ``(layer, slot, pos % max_len)``, every
     other row is left as it was, and stale rows past a slot's position
-    are masked."""
+    are masked. With ``kernel``, attention is the pool kernel's, reading
+    the stack after the rows are written."""
     cfg = reduced_f32(arch)
     rt = Runtime(tp=1, moe_impl="local")
     key = jax.random.PRNGKey(3)
     params, _ = M.init_params(cfg, rt, key)
-    slots, max_len = 4, 24
+    case = _KERNEL_CASE if kernel else dict(max_len=24, pos=[3, 17, 0, 9])
+    if kernel:
+        request.getfixturevalue("pool_kernel")
+    slots, max_len = 4, case["max_len"]
     # stale rows everywhere: a slot may only ever see its own live rows
     state = jax.tree.map(
         lambda a: jax.random.normal(key, a.shape, a.dtype),
         D.init_decode_state(cfg, rt, slots, max_len))
     cache = jax.tree.map(np.array, state["layers"])
-    pos = jnp.array([3, 17, 0, 9], jnp.int32)
+    pos = jnp.array(case["pos"], jnp.int32)
     active = jnp.array([True, True, False, True])
     tokens = jax.random.randint(key, (slots,), 0, cfg.vocab_size)
     step = jax.jit(functools.partial(D.decode_step, cfg, rt))
@@ -154,17 +173,21 @@ def test_slot_pool_decode_matches_a_loop_over_layers(arch):
         tokens = jnp.where(active, jnp.argmax(logits[:, 0], -1), tokens)
 
 
-def _scans(jaxpr):
-    """Every scan equation of a jaxpr, nested ones included."""
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
+        yield eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (tuple, list)) else [param]:
                 if isinstance(sub, jax.extend.core.ClosedJaxpr):
-                    yield from _scans(sub.jaxpr)
+                    yield from _eqns(sub.jaxpr)
                 elif isinstance(sub, jax.extend.core.Jaxpr):
-                    yield from _scans(sub)
+                    yield from _eqns(sub)
+
+
+def _scans(jaxpr):
+    """Every scan equation of a jaxpr, nested ones included."""
+    return (eqn for eqn in _eqns(jaxpr) if eqn.primitive.name == "scan")
 
 
 @pytest.mark.parametrize("arch", ["stablelm-12b", "dbrx-132b",
@@ -199,6 +222,70 @@ def test_decode_step_carries_the_stacked_cache(arch):
             layer_scans.append(eqn)
             assert set(state_out) <= set(eqn.outvars[:n_carry])
     assert len(layer_scans) == 1
+
+
+@pytest.mark.parametrize("arch", ["stablelm-12b", "dbrx-132b"])
+def test_pool_kernel_step_matches_the_layer_view_step(arch, monkeypatch):
+    """The decode step with the pool kernel gives the logits and the cache
+    of the step that attends over each layer's view (the XLA path, which
+    the loop over layers above checks), over three steps from stale rows
+    with one slot inactive; the ``moe`` family takes the kernel too."""
+    cfg = reduced_f32(arch)
+    rt = Runtime(tp=1, moe_impl="local")
+    key = jax.random.PRNGKey(5)
+    params, _ = M.init_params(cfg, rt, key)
+    state0 = jax.tree.map(
+        lambda a: jax.random.normal(key, a.shape, a.dtype),
+        D.init_decode_state(cfg, rt, 4, _KERNEL_CASE["max_len"]))
+    active = jnp.array([True, True, False, True])
+    runs = []
+    for kernel in (False, True):                        # view, then kernel
+        monkeypatch.setattr(A, "_on_tpu", lambda: kernel)
+        step = jax.jit(functools.partial(D.decode_step, cfg, rt))
+        state, pos = state0, jnp.array(_KERNEL_CASE["pos"], jnp.int32)
+        tokens = jax.random.randint(key, (4,), 0, cfg.vocab_size)
+        logits = []
+        for _ in range(3):
+            lg, state = step(params, tokens[:, None], pos, state)
+            logits.append(lg)
+            pos = pos + active.astype(jnp.int32)
+            tokens = jnp.where(active, jnp.argmax(lg[:, 0], -1), tokens)
+        runs.append((logits, state))
+    (view_logits, view_state), (kern_logits, kern_state) = runs
+    for got, want in zip(kern_logits, view_logits):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name in view_state["layers"]:
+        np.testing.assert_allclose(kern_state["layers"][name],
+                                   view_state["layers"][name],
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-12b", "dbrx-132b"])
+def test_pool_kernel_reads_the_stack_whole(arch, pool_kernel):
+    """With the pool kernel, the stacked K and V enter its ``pallas_call``
+    whole, after the row write, and no layer of the stack is sliced or
+    gathered out of it anywhere in the step: the layer view that XLA copied
+    before the attention's dot is gone."""
+    cfg = reduced_f32(arch)
+    rt = Runtime(tp=1, moe_impl="local")
+    params = jax.eval_shape(
+        lambda k: M.init_params(cfg, rt, k)[0], jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda: D.init_decode_state(cfg, rt, 3, 640))
+    tok = jax.ShapeDtypeStruct((3, 1), jnp.int32)
+    pos = jax.ShapeDtypeStruct((3,), jnp.int32)
+    closed = jax.make_jaxpr(functools.partial(D.decode_step, cfg, rt))(
+        params, tok, pos, state)
+    stack = state["layers"]["k"].shape
+    eqns = list(_eqns(closed.jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    whole = [v for v in kernels[0].invars if v.aval.shape == stack]
+    assert len(whole) == 2                          # K and V
+    assert all(w.count > 0 for w in whole)          # written, not the input
+    for eqn in eqns:
+        if eqn.primitive.name in ("dynamic_slice", "gather", "slice",
+                                  "squeeze"):
+            assert not any(v.aval.shape == stack for v in eqn.invars), eqn
 
 
 @pytest.mark.slow

@@ -10,6 +10,7 @@ of this file, in the worker that runs it.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,10 +18,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.membw import membw
 from repro.kernels.tpu import LANE
 from repro.kernels.vai import vai
+from repro.models import attention as attn
 from repro.models import decode as decode_mod
 from repro.models import model as model_mod
 from repro.models.transformer import Runtime
@@ -92,6 +95,28 @@ def test_flash_attention_compiles_bf16(one_chip, head_dim):
     _compile_kernel(functools.partial(flash_attention, causal=True), q, q, q)
 
 
+@pytest.mark.parametrize("slots,max_len,q_heads,kv_heads,head_dim", [
+    (16, 4608, 32, 8, 128),     # mistral-nemo-12b-l8.chat
+    (16, 4224, 48, 8, 128),     # codestral-22b-l8.code
+    (8, 2048, 32, 8, 160),      # stablelm-12b
+    (16, 4096, 40, 40, 128),    # qwen1.5-32b: five blocks of 8 kv heads
+    (16, 4608, 64, 8, 256),     # the VMEM limit shrinks the chunk to 384
+])
+def test_pool_decode_attention_compiles_bf16(one_chip, slots, max_len,
+                                             q_heads, kv_heads, head_dim):
+    chunk = decode_attention.kv_chunk(max_len, q_heads, kv_heads, head_dim,
+                                      2)
+    assert chunk
+    stack = _sds(one_chip, (8, slots, max_len, kv_heads, head_dim),
+                 jnp.bfloat16)
+    _compile_kernel(
+        functools.partial(decode_attention.pool_decode_attention,
+                          scale=head_dim ** -0.5, chunk=chunk),
+        _sds(one_chip, (slots, 1, q_heads, head_dim), jnp.bfloat16),
+        stack, stack, _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (slots,), jnp.int32))
+
+
 @pytest.mark.parametrize("make_space", [
     VaiSpace, MembwSpace, FlashAttentionSpace,
     # lattices that cross the VMEM limit: their largest blocks are pruned
@@ -115,10 +140,8 @@ def test_every_kept_candidate_of_a_space_compiles(one_chip, make_space):
                               for a in args))
 
 
-def test_stablelm_decode_step_compiles_at_full_width(one_chip):
-    """The engine's decode step for stablelm-12b at its published widths
-    (two of its 40 layers), 8 slots x 2048 positions, fits one v5e."""
-    cfg = dataclasses.replace(get_config("stablelm-12b"), n_layers=2)
+def _compile_decode_step(one_chip, cfg, slots, max_len):
+    """The engine's decode step compiled for one v5e."""
     rt = Runtime(tp=1)
     key = _sds(one_chip, (2,), jnp.uint32)
     params = jax.tree.map(
@@ -132,9 +155,55 @@ def test_stablelm_decode_step_compiles_at_full_width(one_chip):
     vec = functools.partial(_sds, one_chip, (slots,))
     step = jax.jit(functools.partial(decode_step, cfg, rt),
                    donate_argnums=(1, 2, 3, 6))
-    compiled = step.lower(params, state, vec(jnp.int32), vec(jnp.int32),
-                          vec(jnp.float32), vec(jnp.bool_), key).compile()
+    return step.lower(params, state, vec(jnp.int32), vec(jnp.int32),
+                      vec(jnp.float32), vec(jnp.bool_), key).compile()
+
+
+def test_stablelm_decode_step_compiles_at_full_width(one_chip):
+    """The engine's decode step for stablelm-12b at its published widths
+    (two of its 40 layers), 8 slots x 2048 positions, fits one v5e."""
+    cfg = dataclasses.replace(get_config("stablelm-12b"), n_layers=2)
+    compiled = _compile_decode_step(one_chip, cfg, 8, 2048)
     mem = compiled.memory_analysis()
     resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < resident < V5E_HBM
+
+
+@pytest.mark.parametrize("arch", ["stablelm-12b", "dbrx-132b",
+                                  "qwen1.5-32b"])
+def test_decode_step_hands_the_stack_to_the_pool_kernel(one_chip, arch,
+                                                        monkeypatch):
+    """Compiled with the pool kernel (selected and compiled for the TPU
+    here, where the backend is the CPU), the step gives the kernel the
+    carried stacks themselves: no instruction makes an array of a layer's
+    shape, in any order of its dims (the view XLA copied before the
+    attention's dot), and none copies or transposes a stack; qwen1.5-32b's
+    40 kv heads take the kernel too. Heads are 128 wide, as in the benchmark's
+    cells: at stablelm-12b's own 160 the compiler lays the stack out with
+    the positions minor and copies it in and out of the step on either
+    path."""
+    monkeypatch.setattr(attn, "_on_tpu", lambda: True)
+    kernel = decode_attention.pool_decode_attention
+    monkeypatch.setattr(
+        decode_attention, "pool_decode_attention",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": False}))
+    cfg = dataclasses.replace(get_config(arch), n_layers=2, head_dim=128)
+    slots, max_len = 8, 2048
+    hlo = _compile_decode_step(one_chip, cfg, slots, max_len).as_text()
+    stack = [2, slots, max_len, cfg.n_kv_heads, cfg.resolved_head_dim]
+    layer = sorted(stack[1:])
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1
+    operands = kernels[0].split("operand_layout_constraints=")[1]
+    assert operands.count(f"[{','.join(map(str, stack))}]") == 2
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+)\(",
+                     line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        assert sorted(dims) != layer, line
+        if dims == stack:
+            assert m.group(2) not in ("copy", "transpose"), line
