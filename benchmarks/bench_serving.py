@@ -75,7 +75,7 @@ def run(verbose: bool = False) -> List[Tuple[str, float, str]]:
             for l in (4, 9, PROMPT_MAX)]
     serve(eng, warm)                       # compile pages + the step graph
     blk = ServeEngine(cfg, rt, params, max_len=MAX_LEN)
-    blk.generate_blocking(
+    blk.generate(
         [Request(r.prompt, max_new_tokens=2) for r in reqs[:SLOTS]])
 
     # the host is shared, so its speed drifts on the tens-of-seconds scale:
@@ -84,7 +84,7 @@ def run(verbose: bool = False) -> List[Tuple[str, float, str]]:
     def _blocking_half(chunks):
         t0 = time.perf_counter()
         for i in chunks:
-            blk.generate_blocking(reqs[i:i + SLOTS])
+            blk.generate(reqs[i:i + SLOTS])
         return time.perf_counter() - t0
 
     starts = list(range(0, N_REQ, SLOTS))

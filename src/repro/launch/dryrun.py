@@ -60,7 +60,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         moe_impl=overrides.get("moe_impl", "ep"),
         remat=overrides.get(
             "remat", "full" if shape.kind == "train" else "none"),
-        decode_impl=overrides.get("decode_impl", "chunked"),
         decode_cache_shard=overrides.get("decode_cache_shard", "none"),
         moe_dispatch_dtype=overrides.get("moe_dispatch_dtype", "bfloat16"),
         moe_capacity_factor=overrides.get("moe_capacity_factor", 1.25),
@@ -168,7 +167,6 @@ def main() -> None:
     ap.add_argument("--moe-impl", default="ep")
     ap.add_argument("--remat", default=None)
     ap.add_argument("--no-zero1", action="store_true")
-    ap.add_argument("--decode-impl", default=None)
     ap.add_argument("--decode-cache-shard", default=None)
     ap.add_argument("--moe-dispatch", default=None,
                     help="f8 = DSv3-style low-precision dispatch a2a")
@@ -203,8 +201,6 @@ def main() -> None:
                              "zero1": not args.no_zero1}
                 if args.remat:
                     overrides["remat"] = args.remat
-                if args.decode_impl:
-                    overrides["decode_impl"] = args.decode_impl
                 if args.decode_cache_shard:
                     overrides["decode_cache_shard"] = args.decode_cache_shard
                 if args.moments:

@@ -2,11 +2,13 @@
 replicate-or-pad head policy, MLA (DeepSeek-V3) with absorbed decode, local
 (windowed) attention with ring-buffer caches, and cross-attention.
 
-The chunked implementation is the XLA-native path used for training and the
-multi-pod dry-run; the Pallas flash kernel (``repro.kernels.flash_attention``)
-is the TPU fast path validated against the same reference. Slot-pool decode
-over the stacked cache runs the Pallas pool kernel
-(``repro.kernels.decode_attention``) on a TPU.
+The chunked implementation is the XLA-native path for training, prefill and
+cross-attention; the Pallas flash kernel (``repro.kernels.flash_attention``)
+is the TPU fast path validated against the same reference. One-token decode
+takes per-sequence positions ``[B]`` and one of two attentions: the Pallas
+pool kernel (``repro.kernels.decode_attention``) where
+:func:`pool_kernel_chunk` gives a chunk, else the single-einsum
+:func:`_dense_decode_attend` over the layer's cache.
 """
 from __future__ import annotations
 
@@ -34,20 +36,17 @@ def _pick_chunk(n: int, pref: int) -> int:
     return max(c, 1)
 
 
-def _block_mask(qpos: jax.Array, kpos: jax.Array, causal: bool, window: int,
-                kv_valid_len) -> jax.Array:
+def _block_mask(qpos: jax.Array, kpos: jax.Array, causal: bool,
+                window: int) -> jax.Array:
     mask = jnp.ones((qpos.shape[0], kpos.shape[0]), dtype=bool)
     if causal:
         mask &= kpos[None, :] <= qpos[:, None]
     if window:
         mask &= kpos[None, :] > qpos[:, None] - window
-    if kv_valid_len is not None:
-        mask &= (kpos < kv_valid_len)[None, :]
     return mask
 
 
-def _fwd_impl(q, k, v, causal, window, scale, qc, kc, q_offset,
-              kv_valid_len):
+def _fwd_impl(q, k, v, causal, window, scale, qc, kc):
     """Online-softmax forward. Returns (out [B,Sq,Hq,Dv], lse [B,Hkv,G,Sq])."""
     B, Sq, Hq, Dk = q.shape
     _, Skv, Hkv, Dv = v.shape
@@ -61,14 +60,14 @@ def _fwd_impl(q, k, v, causal, window, scale, qc, kc, q_offset,
 
     def q_chunk_fn(qi: jax.Array):
         qch = jax.lax.dynamic_slice_in_dim(qg, qi * qc, qc, axis=1)
-        qpos = q_offset + qi * qc + jnp.arange(qc, dtype=jnp.int32)
+        qpos = qi * qc + jnp.arange(qc, dtype=jnp.int32)
 
         def kv_step(carry, inp):
             m, l, acc = carry
             kch, vch, kpos = inp
             s = jnp.einsum("bqhgd,bkhd->bhgqk", qch, kch,
                            preferred_element_type=jnp.float32) * scale
-            mask = _block_mask(qpos, kpos, causal, window, kv_valid_len)
+            mask = _block_mask(qpos, kpos, causal, window)
             s = jnp.where(mask[None, None, None], s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             p = jnp.exp(s - m_new[..., None])
@@ -102,12 +101,12 @@ def _fwd_impl(q, k, v, causal, window, scale, qc, kc, q_offset,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, window, scale, qc, kc):
-    out, _ = _fwd_impl(q, k, v, causal, window, scale, qc, kc, 0, None)
+    out, _ = _fwd_impl(q, k, v, causal, window, scale, qc, kc)
     return out
 
 
 def _flash_fwd(q, k, v, causal, window, scale, qc, kc):
-    out, lse = _fwd_impl(q, k, v, causal, window, scale, qc, kc, 0, None)
+    out, lse = _fwd_impl(q, k, v, causal, window, scale, qc, kc)
     return out, (q, k, v, out, lse)
 
 
@@ -134,7 +133,7 @@ def _flash_bwd(causal, window, scale, qc, kc, res, dout):
         """Recompute p and ds for one (q, kv) block pair (all f32)."""
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qch, kch,
                        preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qpos, kpos, causal, window, None)
+        mask = _block_mask(qpos, kpos, causal, window)
         s = jnp.where(mask[None, None, None], s, NEG_INF)
         p = jnp.exp(s - lse_i[..., None])            # [B,h,g,qc,kc]
         dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_i.astype(jnp.float32),
@@ -218,33 +217,22 @@ def chunked_attention(
     v: jax.Array,                 # [B, Skv, Hkv, Dv]
     *,
     causal: bool = True,
-    q_offset: jax.Array | int = 0,   # absolute position of q[0]
-    kv_valid_len: Optional[jax.Array] = None,  # mask kv positions >= this
     window: int = 0,              # 0 = global; >0 = local attention width
     softmax_scale: Optional[float] = None,
     q_chunk: int = 2048,
     kv_chunk: int = 1024,
 ) -> jax.Array:
-    """Doubly-chunked online-softmax attention; f32 accumulation.
-
-    The differentiable path (training/prefill: static offset, no dynamic
-    kv mask) goes through a flash-style ``custom_vjp`` that recomputes
+    """Doubly-chunked online-softmax attention; f32 accumulation. ``q[0]``
+    sits at position 0 of the keys. A flash-style ``custom_vjp`` recomputes
     probabilities in the backward pass — per-block residuals are never
-    stacked across scan steps. The decode path (traced ``kv_valid_len``)
-    is forward-only.
-    """
+    stacked across scan steps."""
     B, Sq, Hq, Dk = q.shape
     _, Skv, Hkv, Dv = v.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     scale = softmax_scale if softmax_scale is not None else Dk ** -0.5
     qc = _pick_chunk(Sq, q_chunk)
     kc = _pick_chunk(Skv, kv_chunk)
-
-    if kv_valid_len is None and isinstance(q_offset, int) and q_offset == 0:
-        return _flash(q, k, v, causal, window, scale, qc, kc)
-    out, _ = _fwd_impl(q, k, v, causal, window, scale, qc, kc,
-                       q_offset, kv_valid_len)
-    return out
+    return _flash(q, k, v, causal, window, scale, qc, kc)
 
 
 # ---------------------------------------------------------------------------
@@ -318,38 +306,27 @@ def _dense_decode_attend(q: jax.Array, k: jax.Array, v: jax.Array,
     """Single-einsum decode attention: no kv-chunk scan, so a cache whose
     sequence dim is sharded over the model axis partitions cleanly (the
     softmax reductions over the sharded axis become psums — SPMD-friendly).
-    q: [B,1,Hq,Dk]; k/v: [B,S,Hkv,D*]; valid: scalar or per-sequence [B]."""
+    q: [B,1,Hq,Dk]; k/v: [B,S,Hkv,D*]; valid: each sequence's length [B]."""
     B, S, Hkv, Dk = k.shape
     G = q.shape[2] // Hkv
     qg = q.reshape(B, 1, Hkv, G, Dk)
     s = jnp.einsum("bqhgd,bshd->bhgqs", qg, k,
                    preferred_element_type=jnp.float32) * scale
     kv_pos = jnp.arange(S, dtype=jnp.int32)
-    if jnp.ndim(valid) == 1:
-        mask = (kv_pos[None, :] < valid[:, None])[:, None, None, None, :]
-    else:
-        mask = (kv_pos < valid)[None, None, None, None]
+    mask = (kv_pos[None, :] < valid[:, None])[:, None, None, None, :]
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqs,bshd->bqhgd", p.astype(v.dtype), v)
     return out.reshape(B, 1, q.shape[2], v.shape[-1])
 
 
-def _rope_positions(pos: jax.Array) -> jax.Array:
-    """Rope positions of the one new token: ``[B, 1]`` for per-sequence
-    ``pos [B]``, ``[1, 1]`` for a lock-step scalar."""
-    pos = pos.astype(jnp.int32)
-    return pos[:, None] if pos.ndim == 1 else pos.reshape(1, 1)
-
-
 def _write_rows(cache: jax.Array, new: jax.Array, slot: jax.Array,
                 layer: Optional[jax.Array]) -> jax.Array:
     """Write each sequence's new row in place. ``cache`` is one layer's
     ``[B, S, ...]``, or with ``layer`` the stack ``[L, B, S, ...]``; ``new``
-    is ``[B, 1, ...]``; ``slot`` a scalar or per-sequence ``[B]``. One
-    scatter of B rows, whatever the cache's size."""
-    B = new.shape[0]
-    at = (jnp.arange(B), jnp.broadcast_to(slot, (B,)))
+    is ``[B, 1, ...]``; ``slot`` each sequence's row ``[B]``. One scatter
+    of B rows, whatever the cache's size."""
+    at = (jnp.arange(new.shape[0]), slot)
     if layer is not None:
         at = (layer,) + at
     return cache.at[at].set(new[:, 0].astype(cache.dtype))
@@ -381,28 +358,26 @@ def pool_kernel_chunk(cfg: ModelConfig, tp: int, max_len: int) -> int:
 
 
 def decode_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
-                          cache: Dict, pos: jax.Array, window: int = 0,
-                          use_rope: bool = True, impl: str = "chunked",
+                          cache: Dict, pos: jax.Array,
                           layer: Optional[jax.Array] = None, tp: int = 1
                           ) -> Tuple[jax.Array, Dict]:
     """One-token decode: rope the new token's K/V, write them into the cache
-    in place, attend over the cache. ``pos`` is the absolute position — a
-    scalar for lock-step batches, or a per-sequence ``[B]`` vector
-    (slot-pool decode: each sequence ropes, writes and masks at its own
-    position, so batch-mates of different lengths never see each other's
-    padding). ``cache`` holds one layer's ``k``/``v`` ``[B, S, Hkv, hd]``,
-    or with ``layer`` the whole stack ``[L, B, S, Hkv, hd]``, of which only
-    that layer's rows are written and read (the stack stays in the layer
-    scan's carry). Keys are roped at write time; local attention uses a
-    ring buffer of ``window``. Per-sequence ``pos`` over the stack with
-    global attention reads each sequence's live chunks of that layer straight
+    in place, attend over the cache. ``pos`` ``[B]`` is each sequence's
+    absolute position: it ropes, writes and masks at its own position, so
+    batch-mates of different lengths never see each other's padding.
+    ``cache`` holds one layer's ``k``/``v`` ``[B, S, Hkv, hd]``, or with
+    ``layer`` the whole stack ``[L, B, S, Hkv, hd]``, of which only that
+    layer's rows are written and read (the stack stays in the layer scan's
+    carry). Keys are roped at write time; a cache shorter than the
+    sequence is a ring buffer (the hybrid's local attention). Attention
+    over the stack reads each sequence's live chunks of that layer straight
     from the stack through the pool kernel where :func:`pool_kernel_chunk`
-    gives a chunk; elsewhere attention reads the layer's view."""
+    gives a chunk; elsewhere :func:`_dense_decode_attend` reads the layer's
+    view."""
     q, k, v = _qkv(p, x)                      # [B, 1, H(kv), hd]
-    if use_rope:
-        posm = _rope_positions(pos)
-        q = apply_rope(q, posm, cfg.rope_theta)
-        k = apply_rope(k, posm, cfg.rope_theta)
+    posm = pos[:, None]
+    q = apply_rope(q, posm, cfg.rope_theta)
+    k = apply_rope(k, posm, cfg.rope_theta)
     slots = cache["k"].shape[-3]
     slot = (pos % slots).astype(jnp.int32)
     new = {"k": _write_rows(cache["k"], k, slot, layer),
@@ -411,21 +386,15 @@ def decode_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
     # so masking only needs "slot has been written": slot_idx <= pos.
     valid = jnp.minimum(pos + 1, slots)
     scale = cfg.resolved_head_dim ** -0.5
-    chunk = (pool_kernel_chunk(cfg, tp, slots)
-             if pos.ndim == 1 and layer is not None and not window else 0)
+    chunk = pool_kernel_chunk(cfg, tp, slots) if layer is not None else 0
     if chunk:
         from repro.kernels.decode_attention import pool_decode_attention
         out = pool_decode_attention(
             q, new["k"], new["v"], layer, valid, scale=scale, chunk=chunk,
             interpret=jax.default_backend() != "tpu")
     else:
-        ck, cv = _layer_view(new["k"], layer), _layer_view(new["v"], layer)
-        if impl == "dense" or pos.ndim == 1:
-            # per-sequence valid lengths need the batched mask: dense only
-            out = _dense_decode_attend(q, ck, cv, valid, scale)
-        else:
-            out = chunked_attention(q, ck, cv, causal=False,
-                                    kv_valid_len=valid, softmax_scale=scale)
+        out = _dense_decode_attend(q, _layer_view(new["k"], layer),
+                                   _layer_view(new["v"], layer), valid, scale)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new
 
@@ -511,11 +480,11 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array, cache: Dict,
                ) -> Tuple[jax.Array, Dict]:
     """Absorbed-matrix MLA decode: attention runs entirely in the latent
     space — the cache stores only (c_kv, k_rope) per token (the paper-scale
-    memory win of MLA). ``pos`` is a scalar, or a per-sequence ``[B]``
-    vector for slot-pool decode. ``cache`` holds one layer's ``[B, S, *]``
-    latents, or with ``layer`` the stack ``[L, B, S, *]``, written in place
-    at that layer's rows as in :func:`decode_self_attention`."""
-    posm = _rope_positions(pos)
+    memory win of MLA). ``pos`` ``[B]`` is each sequence's position.
+    ``cache`` holds one layer's ``[B, S, *]`` latents, or with ``layer`` the
+    stack ``[L, B, S, *]``, written in place at that layer's rows as in
+    :func:`decode_self_attention`."""
+    posm = pos[:, None]
     q_nope, q_rope = _mla_q(p, cfg, x, posm)         # [B,1,H,*]
     c_new, kr_new = _mla_latent(p, cfg, x, posm)     # [B,1,r], [B,1,rope]
     slot = (pos % cache["c_kv"].shape[-2]).astype(jnp.int32)
@@ -531,11 +500,8 @@ def mla_decode(p: Dict, cfg: ModelConfig, x: jax.Array, cache: Dict,
     s = (jnp.einsum("bshr,btr->bhst", q_lat, ck.astype(q_lat.dtype))
          + jnp.einsum("bshk,btk->bhst", q_rope, kr.astype(q_rope.dtype)))
     s = (s.astype(jnp.float32) * scale)
-    if pos.ndim == 1:
-        s = jnp.where((kv_pos[None, :] < valid[:, None])[:, None, None, :],
-                      s, NEG_INF)
-    else:
-        s = jnp.where((kv_pos < valid)[None, None, None], s, NEG_INF)
+    s = jnp.where((kv_pos[None, :] < valid[:, None])[:, None, None, :],
+                  s, NEG_INF)
     a = jax.nn.softmax(s, axis=-1)
     o_lat = jnp.einsum("bhst,btr->bshr", a.astype(ck.dtype), ck)
     out = jnp.einsum("bshr,rhk->bshk", o_lat, p["wv_b"])

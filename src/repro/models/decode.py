@@ -349,12 +349,11 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
 # ---------------------------------------------------------------------------
 def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
                 pos: jax.Array, state: Dict) -> Tuple[jax.Array, Dict]:
-    """token: [B, 1] int32; pos: next position to write — scalar int32 for
-    lock-step batches, or per-sequence [B] int32 for slot-pool decode
-    (:data:`CAUSAL_CACHE_FAMILIES` only: the recurrent families have no
-    position to index). Returns (logits [B,1,V], new state)."""
+    """token: [B, 1] int32; pos: each sequence's next position to write,
+    [B] int32 (a scalar is broadcast to every sequence; the ssm family
+    reads none). Returns (logits [B,1,V], new state)."""
     x = model_mod.embed(p, cfg, token)
-    pos = pos.astype(jnp.int32)
+    pos = jnp.broadcast_to(pos.astype(jnp.int32), token.shape[:1])
 
     if cfg.family in ("dense", "moe"):
         # the stacked cache rides in the carry: each layer writes its B new
@@ -368,8 +367,8 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
                                            pos, layer=layer)
             else:
                 y, cache = attn.decode_self_attention(
-                    p_layer["attn"], cfg, z, cache, pos,
-                    impl=rt.decode_impl, layer=layer, tp=rt.tp)
+                    p_layer["attn"], cfg, z, cache, pos, layer=layer,
+                    tp=rt.tp)
             h = h + y
             y2, _ = tfm._ffn(p_layer, cfg, rt, h, decode=True)
             return (h + y2, cache), None
@@ -397,8 +396,7 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
             z = rms_norm(h, p_blk["ln1"], cfg.norm_eps)
             if kind == "attn":
                 y, new = attn.decode_self_attention(p_blk["attn"], cfg, z,
-                                                    cache, pos,
-                                                    impl=rt.decode_impl)
+                                                    cache, pos)
             else:
                 y, new = rglru_mod.rglru_decode_step(p_blk["rglru"], cfg, z,
                                                      cache)
@@ -435,8 +433,7 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
                 pl, kv = pl_and_cache
                 z = rms_norm(c, pl["ln1"], cfg.norm_eps)
                 y, new = attn.decode_self_attention(pl["attn"], cfg, z, kv,
-                                                    pos,
-                                                    impl=rt.decode_impl)
+                                                    pos)
                 c = c + y
                 y2, _ = tfm._ffn(pl, cfg, rt, c)
                 return c + y2, new
@@ -466,8 +463,7 @@ def decode_step(cfg: ModelConfig, rt: Runtime, p: Dict, token: jax.Array,
             p_layer, cache = inp
             z = rms_norm(h, p_layer["ln1"], cfg.norm_eps)
             y, new = attn.decode_self_attention(p_layer["attn"], cfg, z,
-                                                cache["self_kv"], pos,
-                                                impl=rt.decode_impl)
+                                                cache["self_kv"], pos)
             h = h + y
             z = rms_norm(h, p_layer["ln_x"], cfg.norm_eps)
             q = jnp.einsum("bsd,dhk->bshk", z, p_layer["xattn"]["wq"])

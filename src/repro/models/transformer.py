@@ -31,9 +31,7 @@ class Runtime:
     moe_impl: str = "local"       # dense | local | ep
     remat: str = "none"           # none | full | dots
     mtp_coef: float = 0.1
-    max_decode_len: int = 0       # 0 -> seq length of the request
     # §Perf knobs (baseline values first)
-    decode_impl: str = "chunked"  # chunked | dense (single-einsum, SPMD)
     decode_cache_shard: str = "none"  # none | seq (cache seq dim -> model)
     moe_dispatch_dtype: str = "bfloat16"  # bfloat16 | f8 (DSv3 fp8 dispatch)
     moe_capacity_factor: float = 1.25

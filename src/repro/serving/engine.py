@@ -1,5 +1,5 @@
-"""Serving engines: a slot-based continuous-batching engine plus the legacy
-blocking facade.
+"""Serving engines: a slot-based continuous-batching engine plus a blocking
+lock-step engine.
 
 :class:`ContinuousEngine` is the JetStream-style core — ``prefill(request)
 -> Prefix``, ``insert(prefix, slot)``, ``generate_step()`` — over a fixed
@@ -16,11 +16,10 @@ the chip model, not guessed — so any :class:`~repro.power.PowerPolicy`
 behind an :class:`~repro.power.EnergySession` caps the decode phase deep
 while leaving prefill at nominal.
 
-:class:`ServeEngine.generate` keeps its blocking signature as a
-compatibility wrapper: greedy calls on slot-capable families route through
-the continuous engine; everything else takes the lock-step path, which
-itself reads logits and decodes at per-sequence positions for the
-causal-cache families (closing the pad-as-context bug there too).
+:class:`ServeEngine.generate` is one lock-step loop over a right-padded
+batch, the only server of the families the slot pool cannot hold. For the
+causal-cache families it reads logits and decodes at per-sequence positions
+(closing the pad-as-context bug there too).
 """
 from __future__ import annotations
 
@@ -299,10 +298,12 @@ class ContinuousEngine:
 
 
 class ServeEngine:
-    """Blocking batch facade over the serving substrate (compatibility
-    wrapper). Greedy calls on slot-capable families route through a pooled
-    :class:`ContinuousEngine`; temperature sampling and the other families
-    take the lock-step path below."""
+    """Blocking lock-step batch engine over the serving substrate: one
+    right-padded prefill, then every sequence decodes in lock-step to the
+    batch-max budget. The only server of the families the slot pool cannot
+    hold (ssm, hybrid, vlm, encdec); :func:`repro.serving.serve` over a
+    :class:`ContinuousEngine` serves dense and moe with per-request
+    budgets."""
 
     def __init__(self, cfg: ModelConfig, rt: Runtime, params,
                  max_len: int = 256,
@@ -313,14 +314,11 @@ class ServeEngine:
         self.session = session
         self.profile = profile      # decode-step roofline profile (if known)
         self._prefill = jax.jit(
-            lambda p, b: decode_mod.prefill(cfg, rt, p, b, max_len))
-        self._prefill_masked = jax.jit(
             lambda p, b, l: decode_mod.prefill(cfg, rt, p, b, max_len,
                                                lengths=l))
         self._decode = jax.jit(
             lambda p, tok, pos, st: decode_mod.decode_step(
                 cfg, rt, p, tok, pos, st))
-        self._cont: Dict[int, ContinuousEngine] = {}  # slot pools, by batch
         self._derived_decode: Optional[StepProfile] = None
 
     def _decode_roofline(self) -> StepProfile:
@@ -345,65 +343,14 @@ class ServeEngine:
                  seed: int = 0, extra_batch: Optional[Dict] = None
                  ) -> List[np.ndarray]:
         """Generate for a batch of requests, blocking until all are done
-        (every output is ``max(r.max_new_tokens)`` long — the legacy
-        contract; per-request budgets need :func:`repro.serving.serve`).
+        (every output is ``max(r.max_new_tokens)`` long; per-request budgets
+        need :func:`repro.serving.serve`). The session sees one observation
+        per decode call.
 
         Short prompts' continuations are independent of the batch max for
-        the causal-cache families (per-sequence prefill masking and decode
+        the causal-cache families (per-sequence prefill lengths and decode
         positions); only the recurrent families (ssm/hybrid) still fold pad
         tokens into their state — batch same-length requests there."""
-        if (self.cfg.family in SLOT_FAMILIES and temperature <= 0.0
-                and extra_batch is None):
-            return self._generate_continuous(requests, seed)
-        return self.generate_blocking(requests, temperature, seed,
-                                      extra_batch)
-
-    # ---------------------------------------------------- continuous route
-    def _generate_continuous(self, requests: List[Request],
-                             seed: int) -> List[np.ndarray]:
-        B = len(requests)
-        eng = self._cont.get(B)
-        if eng is None:
-            eng = self._cont[B] = ContinuousEngine(
-                self.cfg, self.rt, self.params, max_slots=B,
-                max_len=self.max_len, seed=seed)
-        eng._key = jax.random.PRNGKey(seed)
-        plen = min(max(len(r.prompt) for r in requests), self.max_len - 1)
-        max_new = min(max(r.max_new_tokens for r in requests),
-                      self.max_len - plen)
-        outs = [[] for _ in range(B)]
-        for i, r in enumerate(requests):
-            pf = eng.prefill(r)
-            eng.insert(pf, i)
-            outs[i].append(int(pf.token))
-        walls: List[float] = []
-        # legacy cadence: max_new decode calls (the last one's sample is
-        # discarded, as the lock-step loop always did) -> telemetry parity
-        for i in range(max_new):
-            t0 = time.perf_counter()
-            toks = eng.generate_step()
-            toks = np.asarray(toks)
-            wall = time.perf_counter() - t0
-            walls.append(wall)
-            if i + 1 < max_new:
-                for b in range(B):
-                    outs[b].append(int(toks[b]))
-            if self.session is not None and self.profile is None:
-                self.session.observe(
-                    i, scale_profile(self._decode_roofline(), wall), wall)
-        if self.session is not None and self.profile is not None:
-            self.session.observe_many([self.profile] * max_new,
-                                      wall_s=walls, start_step=0)
-        return [np.asarray(o, np.int32) for o in outs]
-
-    # ----------------------------------------------------- lock-step route
-    def generate_blocking(self, requests: List[Request],
-                          temperature: float = 0.0, seed: int = 0,
-                          extra_batch: Optional[Dict] = None
-                          ) -> List[np.ndarray]:
-        """The legacy path: one right-padded prefill, then every sequence
-        decodes in lock-step to the batch-max budget. Kept public as the
-        baseline the continuous engine is benchmarked against."""
         B = len(requests)
         plen = min(max(len(r.prompt) for r in requests), self.max_len - 1)
         prompts = np.zeros((B, plen), np.int32)
@@ -417,17 +364,13 @@ class ServeEngine:
             batch.update(extra_batch)
         key = jax.random.PRNGKey(seed)
 
-        # per-sequence masking for heterogeneous causal-cache batches; the
-        # uniform case keeps the original scalar-position graph bit-for-bit
-        masked = (lengths.min() != lengths.max()
-                  and self.cfg.family in decode_mod.CAUSAL_CACHE_FAMILIES)
-        if masked:
-            logits, state = self._prefill_masked(self.params, batch,
-                                                 jnp.asarray(lengths))
+        if self.cfg.family in decode_mod.CAUSAL_CACHE_FAMILIES:
+            logits, state = self._prefill(self.params, batch,
+                                          jnp.asarray(lengths))
             base_pos = jnp.asarray(lengths)
         else:
-            logits, state = self._prefill(self.params, batch)
-            base_pos = None
+            logits, state = self._prefill(self.params, batch, None)
+            base_pos = jnp.full((B,), plen, jnp.int32)
         max_new = min(max(r.max_new_tokens for r in requests),
                       self.max_len - plen)
         outs = []
@@ -436,10 +379,9 @@ class ServeEngine:
             key, sub = jax.random.split(key)
             tok = self._sample(logits, temperature, sub)
             outs.append(np.asarray(tok))
-            pos = jnp.int32(plen + i) if base_pos is None else base_pos + i
             t0 = time.perf_counter()
-            logits, state = self._decode(self.params, tok[:, None], pos,
-                                         state)
+            logits, state = self._decode(self.params, tok[:, None],
+                                         base_pos + i, state)
             jax.block_until_ready(logits)
             wall = time.perf_counter() - t0
             walls.append(wall)

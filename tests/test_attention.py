@@ -1,10 +1,11 @@
-"""XLA chunked attention: flash custom_vjp fwd/bwd vs naive; masking modes."""
+"""XLA chunked attention: flash custom_vjp fwd/bwd vs naive; masking modes;
+the decode attention's per-sequence length mask."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.attention import chunked_attention
+from repro.models.attention import _dense_decode_attend, chunked_attention
 
 # long-running model/serving tests: fast lane skips these
 pytestmark = pytest.mark.slow
@@ -74,18 +75,17 @@ def test_flash_gradients_match_naive(case):
 
 
 def test_decode_path_valid_len_mask():
-    """Forward-only path with traced kv_valid_len: positions >= valid are
-    ignored regardless of their cache contents."""
+    """Decode attention with each sequence's own length ``valid [B]``:
+    positions at or past a sequence's length are ignored regardless of their
+    cache contents, for two sequences of different lengths."""
     key = jax.random.PRNGKey(2)
     ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (1, 1, 2, 16))
-    k = jax.random.normal(ks[1], (1, 32, 2, 16))
-    v = jax.random.normal(ks[2], (1, 32, 2, 16))
-    valid = jnp.int32(10)
-    out = chunked_attention(q, k, v, causal=False, kv_valid_len=valid,
-                            q_offset=jnp.int32(9))
-    k2 = k.at[:, 10:].set(999.0)
-    v2 = v.at[:, 10:].set(-999.0)
-    out2 = chunked_attention(q, k2, v2, causal=False, kv_valid_len=valid,
-                             q_offset=jnp.int32(9))
+    q = jax.random.normal(ks[0], (2, 1, 4, 16))
+    k = jax.random.normal(ks[1], (2, 32, 2, 16))
+    v = jax.random.normal(ks[2], (2, 32, 2, 16))
+    valid = jnp.array([10, 23], jnp.int32)
+    out = _dense_decode_attend(q, k, v, valid, 16 ** -0.5)
+    k2 = k.at[0, 10:].set(999.0).at[1, 23:].set(999.0)
+    v2 = v.at[0, 10:].set(-999.0).at[1, 23:].set(-999.0)
+    out2 = _dense_decode_attend(q, k2, v2, valid, 16 ** -0.5)
     np.testing.assert_allclose(out, out2, rtol=1e-6, atol=1e-6)

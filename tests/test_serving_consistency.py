@@ -59,6 +59,26 @@ def test_decode_matches_forward(arch):
                                    rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("arch", ["stablelm-12b", "deepseek-v3-671b",
+                                  "recurrentgemma-2b"])
+def test_scalar_pos_is_the_pos_of_every_sequence(arch):
+    """A scalar decode position is that position for every sequence: the
+    step gives exactly the logits and state it gives with ``pos`` broadcast
+    to ``[B]`` (dense, MLA, and the hybrid's ring buffer)."""
+    cfg = reduced_f32(arch)
+    rt = Runtime(tp=1, moe_impl="local")
+    key = jax.random.PRNGKey(4)
+    params, _ = M.init_params(cfg, rt, key)
+    tokens = jax.random.randint(key, (B, S), 0, cfg.vocab_size)
+    _, state = D.prefill(cfg, rt, params, {"tokens": tokens[:, :S - 1]}, S)
+    step = jax.jit(functools.partial(D.decode_step, cfg, rt))
+    pos = jnp.int32(S - 1)
+    scalar = step(params, tokens[:, S - 1:], pos, state)
+    vector = step(params, tokens[:, S - 1:], jnp.full((B,), pos), state)
+    for got, want in zip(jax.tree.leaves(scalar), jax.tree.leaves(vector)):
+        np.testing.assert_array_equal(got, want)
+
+
 def _reference_attention(p, cfg, z, pos, cache, layer):
     """One layer's decode attention, one sequence at a time: write the new
     row at ``pos[b]``, then attend over positions ``0..pos[b]`` in float64.
@@ -329,11 +349,14 @@ def test_serve_engine_heterogeneous_prompts_not_truncated():
     solo = engine.generate([Request(short, max_new_tokens=5),
                             Request(short, max_new_tokens=5)])
     np.testing.assert_array_equal(mixed[0], solo[0])
-    # ... and the lock-step path agrees (same per-sequence masking there)
-    blocking = engine.generate_blocking([Request(short, max_new_tokens=5),
-                                         Request(long, max_new_tokens=5)])
-    np.testing.assert_array_equal(mixed[0], blocking[0])
-    np.testing.assert_array_equal(mixed[1], blocking[1])
+    # ... and serve() over the slot pool agrees (each slot at its own
+    # position there)
+    from repro.serving import ContinuousEngine, serve
+    pool = serve(ContinuousEngine(cfg, rt, params, max_slots=2, max_len=48),
+                 [Request(short, max_new_tokens=5),
+                  Request(long, max_new_tokens=5)]).outputs
+    np.testing.assert_array_equal(mixed[0], pool[0])
+    np.testing.assert_array_equal(mixed[1], pool[1])
 
 
 @pytest.mark.slow

@@ -210,13 +210,15 @@ def served():
 
 @pytest.mark.slow
 def test_continuous_matches_lockstep_greedy_same_length(served):
+    """serve() over the slot pool and the lock-step ServeEngine give the
+    same greedy tokens for same-length prompts and budgets."""
     cfg, rt, params = served
-    engine = ServeEngine(cfg, rt, params, max_len=48)
     rng = np.random.default_rng(0)
     reqs = [Request(rng.integers(0, cfg.vocab_size, 9, dtype=np.int32),
                     max_new_tokens=6) for _ in range(3)]
-    cont = engine.generate(reqs)          # greedy dense -> continuous route
-    lock = engine.generate_blocking(reqs)
+    cont = serve(ContinuousEngine(cfg, rt, params, max_slots=3, max_len=48),
+                 reqs).outputs
+    lock = ServeEngine(cfg, rt, params, max_len=48).generate(reqs)
     for c, l in zip(cont, lock):
         np.testing.assert_array_equal(c, l)
 

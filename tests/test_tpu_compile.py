@@ -147,7 +147,6 @@ def _compile_decode_step(one_chip, cfg, slots, max_len):
     params = jax.tree.map(
         lambda s: _sds(one_chip, s.shape, s.dtype),
         jax.eval_shape(lambda k: model_mod.init_params(cfg, rt, k)[0], key))
-    slots, max_len = 8, 2048
     state = jax.tree.map(
         lambda s: _sds(one_chip, s.shape, s.dtype),
         jax.eval_shape(lambda: decode_mod.init_decode_state(
