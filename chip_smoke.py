@@ -171,14 +171,15 @@ def phase_kernels(reduced: bool, vmem_bytes: int) -> None:
               f"membw ({regime}) differs from kernels.ref")
     del x, out
 
-    # flash attention at stablelm-12b's head_dim, bf16, causal
+    # flash attention at stablelm-12b's head_dim, bf16, causal; one query
+    # head per kv head ([B, Hkv, G, S, D] with G = 1)
     bh, seq = (2, 256) if reduced else (8, 4096)
     q, k, v = (jax.random.normal(jax.random.fold_in(key, 10 + i),
                                  (bh, seq, 160), jnp.bfloat16)
                for i in range(3))
     out = _run_kernel("flash_attention", functools.partial(
-        flash_attention, causal=True, interpret=interpret), (q, k, v),
-        interpret)
+        flash_attention, causal=True, interpret=interpret),
+        (q[None, :, None], k[None], v[None]), interpret)[0, :, 0]
     with jax.default_matmul_precision("highest"):
         want = jax.jit(ref.attention_ref)(q.astype(jnp.float32),
                                           k.astype(jnp.float32),

@@ -29,19 +29,17 @@ def membw_op(x, *, n_chunks: int, n_iters: int,
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
-def flash_attention_op(q, k, v, *, causal: bool = True, block_q: int = 256,
-                       block_k: int = 256,
+def flash_attention_op(q, k, v, lengths=None, *, causal: bool = True,
+                       block_q: int = 256, block_k: int = 256,
                        interpret: bool = False):
-    """q: [B, Sq, Hq, D]; k/v: [B, Skv, Hkv, D/Dv]. GQA-expands KV, folds
-    (batch, heads) into the kernel's leading grid dim."""
-    B, Sq, Hq, D = q.shape
-    _, Skv, Hkv, Dv = v.shape
+    """q: [B, S, Hq, D]; k/v: [B, S, Hkv, D/Dv]; ``lengths`` [B] or None.
+    Each kv head's G = Hq / Hkv query heads go to the kernel as one block
+    against that head's K and V, which are not copied per query head."""
+    B, S, Hq, D = q.shape
+    Hkv, Dv = v.shape[2], v.shape[3]
     G = Hq // Hkv
-    kk = jnp.repeat(k, G, axis=2) if G > 1 else k
-    vv = jnp.repeat(v, G, axis=2) if G > 1 else v
-    qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D)
-    kf = kk.transpose(0, 2, 1, 3).reshape(B * Hq, Skv, D)
-    vf = vv.transpose(0, 2, 1, 3).reshape(B * Hq, Skv, Dv)
-    out = fa.flash_attention(qf, kf, vf, causal=causal, block_q=block_q,
-                             block_k=block_k, interpret=interpret)
-    return out.reshape(B, Hq, Sq, Dv).transpose(0, 2, 1, 3)
+    out = fa.flash_attention(
+        q.reshape(B, S, Hkv, G, D).transpose(0, 2, 3, 1, 4),
+        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), lengths,
+        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, Dv)

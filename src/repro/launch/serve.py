@@ -80,6 +80,23 @@ def latencies_ms(rep: ServeReport) -> Dict[str, np.ndarray]:
             "ITL": 1e3 * np.concatenate([np.zeros(0)] + gaps)}
 
 
+def prefill_attention(rep: ServeReport, blocks: Tuple[int, int]) -> str:
+    """What the prefills' attention visited: the share of the (q, kv)
+    block pairs of each page's full square, over the requests whose
+    prefill ran as the flash kernel at ``blocks``."""
+    r = rep.requests
+    kernel = r.attn_blocks > 0
+    if not kernel.any():
+        return "prefill attention: chunked_attention, every pair of each page"
+    bq, bk = blocks
+    square = ((r.page[kernel] // bq) * (r.page[kernel] // bk)).sum()
+    return (f"prefill attention: flash kernel at blocks {bq} x {bk} for "
+            f"{kernel.sum()} of {len(kernel)} prompts, visited "
+            f"{r.attn_blocks[kernel].sum()} of {square} block pairs "
+            f"({100 * r.attn_blocks[kernel].sum() / square:.1f}% of their "
+            f"pages' squares)")
+
+
 def slowest_tick(rep: ServeReport) -> Optional[str]:
     """The longest decode tick (from the previous step's tokens on the host
     to its own) and the phases that make it up."""
@@ -144,7 +161,7 @@ def main() -> None:
                             slowdown_budget=args.slowdown_budget,
                             freq_mhz=args.freq_mhz,
                             cap_w=args.power_cap_w)
-    _, reqs, rep = run(
+    eng, reqs, rep = run(
         cfg, requests=args.requests, slots=args.slots, max_len=args.max_len,
         prompt_lens=tuple(args.prompt_lens), new_tokens=args.new_tokens,
         rate=args.rate, temperature=args.temperature,
@@ -159,6 +176,7 @@ def main() -> None:
         print(f"decode attention read {read} kv positions, "
               f"{100 * rep.ticks.kv_positions.sum() / read:.1f}% of them "
               f"live")
+    print(prefill_attention(rep, eng.prefill_blocks))
     for name, ms in latencies_ms(rep).items():
         if ms.size:
             p50, p95 = np.percentile(ms, [50, 95])

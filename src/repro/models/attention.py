@@ -2,9 +2,11 @@
 replicate-or-pad head policy, MLA (DeepSeek-V3) with absorbed decode, local
 (windowed) attention with ring-buffer caches, and cross-attention.
 
-The chunked implementation is the XLA-native path for training, prefill and
-cross-attention; the Pallas flash kernel (``repro.kernels.flash_attention``)
-is the TPU fast path validated against the same reference. One-token decode
+The chunked implementation is the XLA-native path for training,
+cross-attention and every prefill the Pallas flash kernel
+(``repro.kernels.flash_attention``) does not take: a prefill's causal
+self-attention runs as that kernel where :func:`prefill_blocks` gives
+blocks (TPU, tp 1, no window, no MLA). One-token decode
 takes per-sequence positions ``[B]`` and one of two attentions: the Pallas
 pool kernel (``repro.kernels.decode_attention``) where
 :func:`pool_kernel_chunk` gives a chunk, else the single-einsum
@@ -283,6 +285,56 @@ def self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
     return y
 
 
+def prefill_blocks(cfg: ModelConfig, tp: int, window: int = 0
+                   ) -> Tuple[int, int]:
+    """The (q, kv) blocks in which a prefill's causal self-attention runs
+    as the Pallas flash kernel (:mod:`repro.kernels.flash_attention`) over
+    a page that both divide, or (0, 0) where it runs as
+    :func:`chunked_attention`: a local window, MLA, heads sharded over
+    ``tp > 1``, a backend other than a TPU, or widths whose grid step no
+    block fits in VMEM."""
+    if window or cfg.use_mla or tp != 1 or not _on_tpu():
+        return 0, 0
+    from repro.kernels.flash_attention import block_sizes
+    return block_sizes(cfg.n_heads // cfg.n_kv_heads, cfg.resolved_head_dim,
+                       jnp.dtype(cfg.dtype).itemsize)
+
+
+def prefill_self_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
+                           positions: jax.Array,
+                           lengths: Optional[jax.Array] = None, tp: int = 1,
+                           window: int = 0
+                           ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """A prefill's causal self-attention over its page: the output and the
+    roped K and V for the cache, as ``self_attention(...,
+    return_cache=True)`` gives them. Where :func:`prefill_blocks` gives
+    blocks that divide the page, attention is the flash kernel, which
+    visits only the blocks below the diagonal and each sequence's first
+    ``lengths`` ``[B]`` positions (rows past the length are not the
+    prompt's and read nothing of use); elsewhere it is
+    :func:`self_attention`'s. Training never comes here: the kernel has no
+    backward pass."""
+    B, S, _ = x.shape
+    bq, bk = prefill_blocks(cfg, tp, window)
+    if not bq or S % bq or S % bk:
+        return self_attention(p, cfg, x, positions, window=window,
+                              return_cache=True)
+    from repro.kernels.flash_attention import flash_attention
+    q, k, v = _qkv(p, x)
+    q = apply_rope(q, positions, cfg.rope_theta)
+    k = apply_rope(k, positions, cfg.rope_theta)
+    Hq, Hkv, hd = q.shape[2], k.shape[2], q.shape[3]
+    G = Hq // Hkv
+    out = flash_attention(
+        q.reshape(B, S, Hkv, G, hd).transpose(0, 2, 3, 1, 4),
+        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), lengths,
+        block_q=bq, block_k=bk,
+        interpret=jax.default_backend() != "tpu")       # [B, Hkv, G, S, hd]
+    y = jnp.einsum("bhgsk,hgkd->bsd", out,
+                   p["wo"].reshape(Hkv, G, hd, -1))
+    return y, (k, v)
+
+
 def cross_attention(p: Dict, cfg: ModelConfig, x: jax.Array,
                     memory: jax.Array) -> jax.Array:
     q, k, v = _qkv(p, x, kv_src=memory)
@@ -338,8 +390,8 @@ def _layer_view(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
 
 
 def _on_tpu() -> bool:
-    """Whether the program runs on a TPU, the backend the pool kernel is
-    compiled for (elsewhere the Pallas interpreter runs it)."""
+    """Whether the program runs on a TPU, the backend the Pallas kernels
+    are compiled for (elsewhere the Pallas interpreter runs them)."""
     return jax.default_backend() == "tpu"
 
 

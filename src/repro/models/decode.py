@@ -200,8 +200,13 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
     prompt's first sampled token is independent of its batch-mates (causal
     attention already keeps positions < length clean; the pad KV entries the
     cache still holds are masked later by per-sequence decode positions).
-    Only meaningful for :data:`CAUSAL_CACHE_FAMILIES`."""
+    Only meaningful for :data:`CAUSAL_CACHE_FAMILIES`; their causal
+    self-attention (:func:`attn.prefill_self_attention`) then visits only
+    each sequence's first ``lengths[b]`` positions where it runs as the
+    flash kernel."""
     tokens = batch["tokens"]
+    if lengths is not None:
+        lengths = jnp.asarray(lengths, jnp.int32)
     B, S = tokens.shape
     M = max_len
     x = model_mod.embed(p, cfg, tokens)
@@ -215,8 +220,8 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
                 y, (a, b) = attn.mla_attention(p_layer["attn"], cfg, z, pos,
                                                return_cache=True)
             else:
-                y, (a, b) = attn.self_attention(p_layer["attn"], cfg, z, pos,
-                                                return_cache=True)
+                y, (a, b) = attn.prefill_self_attention(
+                    p_layer["attn"], cfg, z, pos, lengths, rt.tp)
             h = h + y
             y2, _ = tfm._ffn(p_layer, cfg, rt, h)
             return h + y2, (a, b)
@@ -247,9 +252,9 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
         def block_prefill(p_blk, h, kind):
             z = rms_norm(h, p_blk["ln1"], cfg.norm_eps)
             if kind == "attn":
-                y, (k, v) = attn.self_attention(
-                    p_blk["attn"], cfg, z, pos, window=cfg.local_window,
-                    return_cache=True)
+                y, (k, v) = attn.prefill_self_attention(
+                    p_blk["attn"], cfg, z, pos, tp=rt.tp,
+                    window=cfg.local_window)
                 cache = {"k": _ring_from_kv(k, win),
                          "v": _ring_from_kv(v, win)}
             else:
@@ -286,8 +291,8 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
 
             def inner(c, pl):
                 z = rms_norm(c, pl["ln1"], cfg.norm_eps)
-                y, (k, v) = attn.self_attention(pl["attn"], cfg, z, pos,
-                                                return_cache=True)
+                y, (k, v) = attn.prefill_self_attention(
+                    pl["attn"], cfg, z, pos, lengths, rt.tp)
                 c = c + y
                 y2, _ = tfm._ffn(pl, cfg, rt, c)
                 return c + y2, (k, v)
@@ -314,8 +319,8 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
         def body(carry, p_layer):
             h = carry
             z = rms_norm(h, p_layer["ln1"], cfg.norm_eps)
-            y, (k, v) = attn.self_attention(p_layer["attn"], cfg, z, pos,
-                                            return_cache=True)
+            y, (k, v) = attn.prefill_self_attention(
+                p_layer["attn"], cfg, z, pos, lengths, rt.tp)
             h = h + y
             z = rms_norm(h, p_layer["ln_x"], cfg.norm_eps)
             qx, kx, vx = attn._qkv(p_layer["xattn"], z, kv_src=memory)
@@ -338,7 +343,7 @@ def prefill(cfg: ModelConfig, rt: Runtime, p: Dict, batch: Dict,
                 f"per-sequence prefill lengths need a position-indexed "
                 f"cache; the recurrent state of family {cfg.family!r} "
                 f"absorbs pad tokens")
-        idx = (jnp.asarray(lengths, jnp.int32) - 1)[:, None, None]
+        idx = (lengths - 1)[:, None, None]
         x_last = jnp.take_along_axis(x, idx, axis=1)
     logits = model_mod.logits_fn(p, cfg, x_last)
     return logits, state

@@ -131,6 +131,7 @@ class Prefix:
     length: int                   # true prompt length
     max_new: int                  # decode budget (first token included)
     temperature: float = 0.0
+    page: int = 0                 # positions the prefill computed
 
 
 class ContinuousEngine:
@@ -194,6 +195,10 @@ class ContinuousEngine:
         # the chunk in which the step's attention reads each slot's written
         # positions (the pool kernel), or 0: it reads all max_len of them
         self.kv_chunk = attn.pool_kernel_chunk(cfg, rt.tp, max_len)
+        # the (q, kv) blocks in which a prefill's attention visits only the
+        # causal, live blocks of a page they divide (the flash kernel), or
+        # (0, 0): every page's attention is chunked_attention's
+        self.prefill_blocks = attn.prefill_blocks(cfg, rt.tp)
 
     # ------------------------------------------------------------- prefill
     def _bucket(self, length: int) -> int:
@@ -239,7 +244,7 @@ class ContinuousEngine:
         self.n_prefills += 1
         max_new = max(1, min(request.max_new_tokens, self.max_len - L))
         return Prefix(state=state, token=tok, length=L, max_new=max_new,
-                      temperature=temperature)
+                      temperature=temperature, page=page)
 
     # -------------------------------------------------------------- insert
     def _make_insert(self, page: int):

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -77,6 +77,10 @@ class RequestRecords:
     first_tick: np.ndarray      # the first decode step that stepped it
     last_tick: np.ndarray       # the last
     prompt_len: np.ndarray      # prompt tokens prefilled
+    page: np.ndarray            # positions its prefill computed (0: unknown)
+    attn_blocks: np.ndarray     # (q, kv) block pairs its prefill attention
+    #                             visited as the flash kernel (0: it ran as
+    #                             chunked_attention, every pair of the page)
 
 
 @dataclass
@@ -113,6 +117,16 @@ def _kv_read(pos: np.ndarray, max_len: int, chunk: int) -> int:
     return int((-(-extent // chunk)).sum()) * chunk
 
 
+def _attn_blocks(length: int, page: int, blocks: Tuple[int, int]) -> int:
+    """(q, kv) block pairs a prefill's attention visited: the flash
+    kernel's count where both ``blocks`` divide the page, else 0."""
+    bq, bk = blocks
+    if not bq or not page or page % bq or page % bk:
+        return 0
+    from repro.kernels.flash_attention import visited_blocks
+    return visited_blocks(length, page, bq, bk)
+
+
 def _span(name: str) -> TraceAnnotation:
     span = TraceAnnotation(name)
     span.__enter__()
@@ -139,8 +153,10 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
     arr_sorted = arr[order]
 
     S = engine.max_slots
-    # an engine without ``kv_chunk`` reads every position of every slot
+    # an engine without ``kv_chunk`` reads every position of every slot,
+    # one without ``prefill_blocks`` prefills through chunked_attention
     chunk = getattr(engine, "kv_chunk", 0)
+    blocks = getattr(engine, "prefill_blocks", (0, 0))
     outputs: List[Optional[np.ndarray]] = [None] * n
     partial: List[Optional[List[int]]] = [None] * n
     slot_req = [-1] * S                 # request index occupying each slot
@@ -157,6 +173,8 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
     first_tick = np.full(n, -1, np.int64)
     last_tick = np.full(n, -1, np.int64)
     prompt_len = np.zeros(n, np.int64)
+    page = np.zeros(n, np.int64)
+    attn_blocks = np.zeros(n, np.int64)
     t0 = time.perf_counter()
     reached, reached_t = [0], [t0]      # step counter values, when reached
     begun = None                        # the tick under way began
@@ -183,6 +201,8 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
                 reached_t.append(a0)
             admit_t[i] = a0
             prompt_len[i] = pf.length
+            page[i] = getattr(pf, "page", 0)
+            attn_blocks[i] = _attn_blocks(pf.length, page[i], blocks)
             n_adm += 1
             adm_tokens += pf.length
             # keep the first token as a device scalar: forcing it here would
@@ -255,5 +275,6 @@ def serve(engine, requests: Sequence, arrivals: Optional[Sequence] = None,
         ticks=TickRecords.from_rows(rows),
         requests=RequestRecords(due_s=due, admit_s=admit_t,
                                 first_tick=first_tick, last_tick=last_tick,
-                                prompt_len=prompt_len),
+                                prompt_len=prompt_len, page=page,
+                                attn_blocks=attn_blocks),
         session=getattr(engine, "session", None))

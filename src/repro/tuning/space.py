@@ -433,6 +433,9 @@ class FlashAttentionSpace(KernelSpace):
     across the (sequential, innermost) kv axis, so K and V are re-fetched
     once per *q block* — larger ``block_q`` means fewer K/V re-reads,
     which is exactly the traffic/occupancy trade the tuner explores.
+    Under ``causal`` only the blocks the kernel visits count: those that
+    hold a pair on or below the diagonal, whose K and V alone it fetches.
+    The sequences are ``batch_heads`` single-head self-attentions.
     """
 
     kernel = "flash_attention"
@@ -440,7 +443,7 @@ class FlashAttentionSpace(KernelSpace):
     tol = 2e-5                     # f32 contract of tests/test_kernels.py
 
     def __init__(self, batch_heads: int = 4, seq_q: int = 1024,
-                 seq_kv: Optional[int] = None, head_dim: int = 128,
+                 head_dim: int = 128,
                  value_dim: Optional[int] = None, causal: bool = True,
                  block_q_options: Sequence[int] = (128, 256, 512),
                  block_k_options: Sequence[int] = (128, 256, 512),
@@ -449,8 +452,6 @@ class FlashAttentionSpace(KernelSpace):
         super().__init__(chip, vmem_limit_bytes)
         self.batch_heads = _check_positive_int("batch_heads", batch_heads)
         self.seq_q = _check_positive_int("seq_q", seq_q)
-        self.seq_kv = self.seq_q if seq_kv is None \
-            else _check_positive_int("seq_kv", seq_kv)
         self.head_dim = _check_positive_int("head_dim", head_dim)
         self.value_dim = self.head_dim if value_dim is None \
             else _check_positive_int("value_dim", value_dim)
@@ -479,8 +480,8 @@ class FlashAttentionSpace(KernelSpace):
             return f"mxu-misaligned (blocks must be multiples of {MXU})"
         if self.seq_q % bq:
             return f"indivisible (seq_q {self.seq_q} % block_q {bq})"
-        if self.seq_kv % bk:
-            return f"indivisible (seq_kv {self.seq_kv} % block_k {bk})"
+        if self.seq_q % bk:
+            return f"indivisible (seq_q {self.seq_q} % block_k {bk})"
         footprint = self._footprint(bq, bk)
         if footprint > self.vmem_limit_bytes:
             return (f"vmem-overflow ({footprint} B > "
@@ -490,34 +491,33 @@ class FlashAttentionSpace(KernelSpace):
     def _candidate(self, config: Config) -> Candidate:
         cfg = dict(config)
         bq, bk = cfg["block_q"], cfg["block_k"]
-        bh, sq, skv = self.batch_heads, self.seq_q, self.seq_kv
+        bh, s = self.batch_heads, self.seq_q
         d, dv = self.head_dim, self.value_dim
-        nq, nk = sq // bq, skv // bk
-        # the kernel evaluates every (qi, kj) block even under the causal
-        # mask (masked, not skipped), so flops are the full rectangle
-        flops = 2.0 * bh * sq * skv * (d + dv)
-        # q + o move once; k/v are re-fetched once per q block
-        traffic = 4.0 * bh * (sq * d + sq * dv + nq * skv * (d + dv))
+        from repro.kernels.flash_attention import visited_blocks
+        visited = visited_blocks(s, s, bq, bk, self.causal)
+        # a visited block pair computes its whole [bq, bk] tile; q + o
+        # move once, and k/v once per visited pair
+        flops = 2.0 * bh * visited * bq * bk * (d + dv)
+        traffic = 4.0 * bh * (s * d + s * dv + visited * bk * (d + dv))
         return Candidate(kernel=self.kernel, config=config, flops=flops,
                          hbm_bytes=traffic,
                          vmem_bytes=self._footprint(bq, bk),
-                         grid_steps=bh * nq * nk)
+                         grid_steps=bh * (s // bq) * (s // bk))
 
     def _get_qkv(self):
         if self._qkv is None:
             import jax
             import jax.numpy as jnp
             key = jax.random.PRNGKey(self.seed)
+            bh, s = self.batch_heads, self.seq_q
+            # the kernel's layout: [B, Hkv, G, S, D] and [B, Hkv, S, D]
             self._qkv = (
                 jax.random.normal(jax.random.fold_in(key, 0),
-                                  (self.batch_heads, self.seq_q,
-                                   self.head_dim), jnp.float32),
+                                  (bh, 1, 1, s, self.head_dim), jnp.float32),
                 jax.random.normal(jax.random.fold_in(key, 1),
-                                  (self.batch_heads, self.seq_kv,
-                                   self.head_dim), jnp.float32),
+                                  (bh, 1, s, self.head_dim), jnp.float32),
                 jax.random.normal(jax.random.fold_in(key, 2),
-                                  (self.batch_heads, self.seq_kv,
-                                   self.value_dim), jnp.float32))
+                                  (bh, 1, s, self.value_dim), jnp.float32))
         return self._qkv
 
     def program(self, candidate: Candidate, interpret: bool):
@@ -533,7 +533,9 @@ class FlashAttentionSpace(KernelSpace):
     def _reference(self, candidate: Candidate):
         from repro.kernels import ref
         q, k, v = self._get_qkv()
-        return ref.attention_ref(q, k, v, causal=self.causal)
+        out = ref.attention_ref(q[:, 0, 0], k[:, 0], v[:, 0],
+                                causal=self.causal)
+        return out[:, None, None]
 
     def profile(self, candidate: Candidate, model: ChipModel,
                 perf: PerfParams) -> StepProfile:

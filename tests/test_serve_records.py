@@ -116,6 +116,46 @@ def test_request_records_hand_worked(worked):
     np.testing.assert_array_equal(r.prompt_len, LENS)
 
 
+class _PagedEngine(_ClockedEngine):
+    """Prefills each prompt at a power-of-two page; its prefill attention
+    runs as the flash kernel at ``prefill_blocks`` where they divide the
+    page."""
+    prefill_blocks = (4, 4)
+
+    def prefill(self, request, temperature=0.0):
+        pf = super().prefill(request, temperature)
+        pf.page = 1 << (pf.length - 1).bit_length()
+        return pf
+
+
+def test_request_records_count_prefill_attention_blocks(clock):
+    """Each request records its page and the (q, kv) block pairs the
+    kernel visited, the kernel's own host count (the causal triangle's
+    blocks up to the length); 0 where the page is smaller than the block
+    and the prefill ran as chunked_attention. The launcher's line gives
+    their share of the pages' squares. An engine without pages or a
+    block records 0 for both."""
+    from repro.kernels.flash_attention import visited_blocks
+    from repro.launch.serve import prefill_attention
+    eng = _PagedEngine(clock, max_slots=2)
+    r = serve(eng, _requests(LENS, BUDGETS), arrivals=ARRIVALS).requests
+    np.testing.assert_array_equal(r.page, [4, 8, 4, 2, 8])
+    # 5 of 8: q block 0 sees kv block 0; q block 1 (rows 4-7) kv 0 and 1
+    np.testing.assert_array_equal(r.attn_blocks, [1, 3, 1, 0, 3])
+    assert [visited_blocks(n, p, 4, 4) for n, p in
+            [(3, 4), (5, 8), (4, 4), (6, 8)]] == [1, 3, 1, 3]
+    assert prefill_attention(serve(eng, _requests(LENS, BUDGETS),
+                                   arrivals=ARRIVALS), (4, 4)) == (
+        "prefill attention: flash kernel at blocks 4 x 4 for 4 of 5 prompts, "
+        "visited 8 of 10 block pairs (80.0% of their pages' squares)")
+    plain = serve(_ClockedEngine(clock, max_slots=2),
+                  _requests(LENS, BUDGETS), arrivals=ARRIVALS)
+    np.testing.assert_array_equal(plain.requests.page, 0)
+    np.testing.assert_array_equal(plain.requests.attn_blocks, 0)
+    assert prefill_attention(plain, (0, 0)) == (
+        "prefill attention: chunked_attention, every pair of each page")
+
+
 def test_report_counts_from_records(worked):
     eng, rep = worked
     assert rep.n_steps == 3

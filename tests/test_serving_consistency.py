@@ -2,6 +2,7 @@
 reproduce the teacher-forced forward logits for EVERY architecture family
 (KV caches, MLA latent cache, SSM state, RG-LRU state, ring buffers,
 cross-attention caches all exercised)."""
+import dataclasses
 import functools
 
 import jax
@@ -379,3 +380,125 @@ def test_serve_engine_session_telemetry():
     assert len(session.decisions) == 6          # one per decode step
     assert session.total_energy_j() > 0
     assert session.mode_hours_pct() == {2: 100.0}   # decode is mode 2 (M.I.)
+
+
+# ---------------------------------------------------------------------------
+# Prefill attention through the flash kernel
+# ---------------------------------------------------------------------------
+def _hd128(arch="stablelm-12b", n_layers=2):
+    """A reduced config at the benchmark cells' 128-wide heads."""
+    return dataclasses.replace(reduced_f32(arch), n_layers=n_layers,
+                               head_dim=128)
+
+
+def test_prefill_block_predicate(monkeypatch):
+    """The kernel takes a prefill's self-attention only where it is causal
+    over the whole page on one TPU: never MLA, a local window, heads over
+    tp > 1 or another backend."""
+    dense = _hd128()
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    assert A.prefill_blocks(dense, 1) == (512, 512)
+    assert A.prefill_blocks(dense, 2) == (0, 0)
+    assert A.prefill_blocks(dense, 1, window=64) == (0, 0)
+    assert A.prefill_blocks(reduced_f32("deepseek-v3-671b"), 1) == (0, 0)
+    monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    assert A.prefill_blocks(dense, 1) == (0, 0)
+
+
+def test_engine_prefill_kernel_matches_chunked(monkeypatch):
+    """ContinuousEngine's prefill through the flash kernel (interpreted)
+    gives the first token and the cache rows below the prompt's length of
+    the ``chunked_attention`` route: a page the block divides, partly and
+    wholly filled, and one page that takes two blocks a side."""
+    from repro.serving import ContinuousEngine, Request
+    cfg = _hd128()
+    rt = Runtime(tp=1)
+    params, _ = M.init_params(cfg, rt, jax.random.PRNGKey(6))
+    rng = np.random.default_rng(6)
+    reqs = [Request(rng.integers(0, cfg.vocab_size, n, dtype=np.int32))
+            for n in (300, 512, 700)]
+    runs = []
+    for kernel in (False, True):
+        monkeypatch.setattr(A, "_on_tpu", lambda: kernel)
+        eng = ContinuousEngine(cfg, rt, params, max_slots=1, max_len=1024)
+        assert eng.prefill_blocks == ((512, 512) if kernel else (0, 0))
+        runs.append([eng.prefill(r) for r in reqs])
+    for chunked, flash in zip(*runs):
+        assert flash.page in (512, 1024) and flash.page == chunked.page
+        assert int(flash.token) == int(chunked.token)
+        for name, rows in flash.state["layers"].items():
+            np.testing.assert_allclose(
+                rows[:, :, :flash.length],
+                chunked.state["layers"][name][:, :, :flash.length],
+                rtol=2e-5, atol=2e-5)
+
+
+def test_serve_engine_mixed_batch_prefill_kernel(monkeypatch):
+    """A mixed-length ServeEngine batch prefilled through the flash kernel
+    (each sequence bounded by its own length) gives every sequence the
+    first token and cache rows of the ``chunked_attention`` route."""
+    from repro.serving import ServeEngine
+    cfg = _hd128()
+    rt = Runtime(tp=1)
+    params, _ = M.init_params(cfg, rt, jax.random.PRNGKey(7))
+    lengths = np.array([512, 300, 40], np.int32)
+    rng = np.random.default_rng(7)
+    tokens = np.zeros((3, 512), np.int32)
+    for i, n in enumerate(lengths):
+        tokens[i, :n] = rng.integers(0, cfg.vocab_size, n)
+    runs = []
+    for kernel in (False, True):
+        monkeypatch.setattr(A, "_on_tpu", lambda: kernel)
+        eng = ServeEngine(cfg, rt, params, max_len=576)
+        runs.append(eng._prefill(params, {"tokens": jnp.asarray(tokens)},
+                                 jnp.asarray(lengths)))
+    (lg0, st0), (lg1, st1) = runs
+    np.testing.assert_array_equal(jnp.argmax(lg1, -1), jnp.argmax(lg0, -1))
+    np.testing.assert_allclose(lg1, lg0, rtol=1e-4, atol=1e-4)
+    for name in st0["layers"]:
+        for b, n in enumerate(lengths):
+            np.testing.assert_allclose(st1["layers"][name][:, b, :n],
+                                       st0["layers"][name][:, b, :n],
+                                       rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("arch,tp", [("stablelm-12b", 2),
+                                     ("deepseek-v3-671b", 1),
+                                     ("recurrentgemma-2b", 1)])
+def test_prefill_kernel_stays_off_other_paths(arch, tp, monkeypatch):
+    """With the kernel selectable (a TPU backend, patched here), the
+    prefills of tp > 1, MLA and the hybrid's local windows trace no Pallas
+    kernel: they keep ``chunked_attention``."""
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(reduced_f32(arch), head_dim=128)
+    rt = Runtime(tp=1, moe_impl="local")
+    params = jax.eval_shape(
+        lambda k: M.init_params(cfg, rt, k)[0], jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32)
+    lengths = None if cfg.family == "hybrid" else jnp.array([300])
+    jaxpr = jax.make_jaxpr(lambda p, t: D.prefill(
+        cfg, Runtime(tp=tp, moe_impl="local"), p, {"tokens": t}, 512,
+        lengths=lengths))(params, tokens).jaxpr
+    assert not any(e.primitive.name == "pallas_call" for e in _eqns(jaxpr))
+
+
+def test_training_grad_with_the_prefill_kernel_selected(monkeypatch):
+    """Training never reaches the forward-only kernel: with it selectable,
+    the loss's gradient traces no Pallas kernel and is the gradient
+    without it."""
+    cfg = _hd128(n_layers=1)
+    rt = Runtime(tp=1)
+    params, _ = M.init_params(cfg, rt, jax.random.PRNGKey(8))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(9), (1, 512), 0,
+                                          cfg.vocab_size)}
+
+    def grad(p):
+        return jax.grad(lambda q: M.loss_fn(cfg, rt, q, batch)[0])(p)
+
+    want = jax.jit(grad)(params)
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    assert not any(e.primitive.name == "pallas_call"
+                   for e in _eqns(jax.make_jaxpr(grad)(params).jaxpr))
+    got = jax.jit(grad)(params)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(g, w)

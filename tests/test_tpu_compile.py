@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import decode_attention
+from repro.kernels import flash_attention as flash_attention_mod
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.membw import membw
 from repro.kernels.tpu import LANE
@@ -91,8 +92,30 @@ def test_membw_compiles(one_chip, chunk_bytes, n_chunks):
 
 @pytest.mark.parametrize("head_dim", [160, 128])
 def test_flash_attention_compiles_bf16(one_chip, head_dim):
-    q = _sds(one_chip, (8, 4096, head_dim), jnp.bfloat16)
-    _compile_kernel(functools.partial(flash_attention, causal=True), q, q, q)
+    q = _sds(one_chip, (1, 8, 1, 4096, head_dim), jnp.bfloat16)
+    k = _sds(one_chip, (1, 8, 4096, head_dim), jnp.bfloat16)
+    _compile_kernel(functools.partial(flash_attention, causal=True), q, k, k)
+
+
+@pytest.mark.parametrize("q_heads,kv_heads,head_dim,blocks", [
+    (32, 8, 128, (512, 512)),   # mistral-nemo-12b-l8.chat
+    (48, 8, 128, (256, 512)),   # codestral-22b-l8.code
+    (32, 8, 160, (256, 512)),   # stablelm-12b: heads of 1.25 lanes
+    (40, 40, 128, (512, 512)),  # qwen1.5-32b: one query head a kv head
+])
+def test_prefill_flash_attention_compiles_bf16(one_chip, q_heads, kv_heads,
+                                               head_dim, blocks):
+    """The prefill's kernel at both cells' widths (and two others) over a
+    page of 4096, at the blocks the route picks for them, with
+    per-sequence lengths: the VMEM count never picks blocks the compiler
+    refuses."""
+    groups = q_heads // kv_heads
+    assert flash_attention_mod.block_sizes(groups, head_dim, 2) == blocks
+    q = _sds(one_chip, (1, kv_heads, groups, 4096, head_dim), jnp.bfloat16)
+    k = _sds(one_chip, (1, kv_heads, 4096, head_dim), jnp.bfloat16)
+    _compile_kernel(functools.partial(flash_attention, block_q=blocks[0],
+                                      block_k=blocks[1]),
+                    q, k, k, _sds(one_chip, (1,), jnp.int32))
 
 
 @pytest.mark.parametrize("slots,max_len,q_heads,kv_heads,head_dim", [
@@ -167,6 +190,38 @@ def test_stablelm_decode_step_compiles_at_full_width(one_chip):
     resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < resident < V5E_HBM
+
+
+def test_prefill_compiles_with_the_flash_kernel(one_chip, monkeypatch):
+    """The engine's prefill program at a page of 4096 (two layers at
+    codestral-22b's widths: 48 x 128 heads, 8 kv heads) with its attention
+    selected as the flash kernel and compiled for the TPU: one kernel, in
+    the layer scan, and the program fits one v5e."""
+    monkeypatch.setattr(attn, "_on_tpu", lambda: True)
+    kernel = flash_attention_mod.flash_attention
+    monkeypatch.setattr(
+        flash_attention_mod, "flash_attention",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": False}))
+    cfg = dataclasses.replace(get_config("stablelm-12b"), n_layers=2,
+                              n_heads=48, n_kv_heads=8, d_model=6144,
+                              d_ff=16384, head_dim=128)
+    rt = Runtime(tp=1)
+    params = jax.tree.map(
+        lambda s: _sds(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda k: model_mod.init_params(cfg, rt, k)[0],
+                       _sds(one_chip, (2,), jnp.uint32)))
+
+    def prefill(p, tokens, lengths):
+        return decode_mod.prefill(cfg, rt, p, {"tokens": tokens}, 4096,
+                                  lengths=lengths)
+
+    compiled = jax.jit(prefill).lower(
+        params, _sds(one_chip, (1, 4096), jnp.int32),
+        _sds(one_chip, (1,), jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes + mem.argument_size_in_bytes < V5E_HBM
 
 
 @pytest.mark.parametrize("arch", ["stablelm-12b", "dbrx-132b",

@@ -65,6 +65,20 @@ class TestPruning:
         kept, pruned = tight.enumerate_all()
         assert not kept and "vmem-overflow" in pruned[0][1]
 
+    def test_flash_attention_counts_visited_blocks(self):
+        """Under ``causal`` the candidate's FLOPs and K/V traffic are those
+        of the block pairs the kernel visits (on or below the diagonal:
+        10 of 16 at 512 / 128), not the full square it used to mask."""
+        kw = dict(batch_heads=2, seq_q=512, head_dim=64,
+                  block_q_options=(128,), block_k_options=(128,))
+        causal = FlashAttentionSpace(**kw).candidates()[0]
+        full = FlashAttentionSpace(causal=False, **kw).candidates()[0]
+        assert full.flops == 2.0 * 2 * 512 * 512 * 128
+        assert causal.flops == full.flops * 10 / 16
+        qo = 4.0 * 2 * 2 * 512 * 64
+        assert causal.hbm_bytes - qo == (full.hbm_bytes - qo) * 10 / 16
+        assert causal.grid_steps == full.grid_steps == 2 * 16
+
     def test_membw_chunk_rules(self):
         space = MembwSpace(total_rows=2048, n_iters=4,
                            n_chunks_options=(1, 3, 8, 2048))
